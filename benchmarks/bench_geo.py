@@ -26,7 +26,8 @@ SCALES = (("small", 800), ("large", 6000))
 SHARDS = (1, 2, 4)
 
 
-def _dataset(n_per_class: int):
+def dataset(n_per_class: int):
+    """The suite's lgd dataset at one scale (cached per process)."""
     if n_per_class not in _GEO_CACHE:
         _GEO_CACHE[n_per_class] = synth_rdf.make_lgd(
             n_per_class=n_per_class, seed=3, block=1024)
@@ -43,7 +44,8 @@ def _patterns(ns, cls, suffix=""):
     )
 
 
-def _queries(ns) -> list:
+def queries(ns) -> list:
+    """The four Geographica selection shapes over an lgd dataset."""
     pa, ga, pats_a = _patterns(ns, "class:hotel")
     pb, gb, pats_b = _patterns(ns, "class:park", "2")
     return [
@@ -66,12 +68,12 @@ def _queries(ns) -> list:
 def run() -> list:
     rows = []
     for scale, n_per_class in SCALES:
-        ds = _dataset(n_per_class)
+        ds = dataset(n_per_class)
         for n_shards in SHARDS:
             store = (ds.store if n_shards == 1
                      else shard_store(ds.store, n_shards))
             eng = StreakEngine(store)
-            for shape, q in _queries(ds.ns):
+            for shape, q in queries(ds.ns):
                 scores, _, _ = eng.execute(q)  # warm scan cache + card check
                 t = common.timeit(lambda: eng.execute(q))
                 rows.append(common.row(

@@ -22,6 +22,11 @@ def _parse_row(row: str) -> dict:
 
 
 def main() -> None:
+    from pathlib import Path
+
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache(Path(__file__).resolve().parents[1])
     from . import (bench_aps, bench_engines, bench_geo, bench_join,
                    bench_kernels, bench_refine, bench_serve, bench_sip,
                    bench_sizes, bench_vary_k)

@@ -172,12 +172,9 @@ def resolve_probe_backend(backend: str | None) -> str:
     if b != "auto":
         return b
     if _auto_backend is None:
-        try:
-            import jax
-            _auto_backend = ("kernel" if jax.default_backend() == "tpu"
-                             else "numpy")
-        except Exception:  # pragma: no cover - jax is a hard dep in practice
-            _auto_backend = "numpy"
+        import jax
+        _auto_backend = ("kernel" if jax.default_backend() == "tpu"
+                         else "numpy")
     return _auto_backend
 
 

@@ -32,13 +32,17 @@ retires only that request) lives in `serve/spatial.py`.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import logging
 import threading
 import time
 import zlib
 
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 
 class InjectedFault(RuntimeError):
@@ -204,6 +208,9 @@ class FaultStats:
     exhausted: int = 0            # chains with no surviving backend
     breaker_opens: int = 0
     policy_demotions: int = 0     # plan-time reroutes around open breakers
+    # successful attempts per (op, backend): which route really ran
+    calls: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
 
 
 class FaultState:
@@ -318,7 +325,10 @@ def run_op(op: str, attempts: list, validate=None):
     per-(op, backend) breaker records the failure and the next backend
     runs. `validate` is the op's cheap structural check (the
     corrupt-then-detect hook); it runs only under an installed plan so the
-    fault-free hot path never pays for it.
+    fault-free hot path never pays for it. The first failure of each
+    (op, backend) is logged as a warning, so a backend that cannot run on
+    this platform (a kernel the compiler refuses, say) does not hide behind
+    its bit-identical fallback; `STATE.stats.calls` counts the successes.
 
     Raises FallbackExhausted when no backend survives.
     """
@@ -355,11 +365,16 @@ def run_op(op: str, attempts: list, validate=None):
                 br.ok()
             if ai:
                 st.stats.fallbacks += 1
+            st.stats.calls[(op, backend)] += 1
             return out
         except Exception as e:      # noqa: BLE001 — any failure fails over
-            was_open = st.breaker(op, backend).open
-            st.breaker(op, backend).fail()
-            if not was_open and st.breaker(op, backend).open:
+            if (op, backend) not in st.breakers:   # its first failure
+                log.warning("%s: backend %r failed, failing over: %s: %s",
+                            op, backend, type(e).__name__, e)
+            br = st.breaker(op, backend)
+            was_open = br.open
+            br.fail()
+            if not was_open and br.open:
                 st.stats.breaker_opens += 1
             st.stats.failures += 1
             if isinstance(e, OpTimeout):

@@ -13,12 +13,11 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..launch.mesh import _axis_kwargs
+from ..launch.mesh import auto_axes
 
 
-def _compat_mesh(devices: np.ndarray, axis_names: tuple) -> Mesh:
-    """Mesh construction across jax versions (axis_types is recent API)."""
-    return Mesh(devices, axis_names, **_axis_kwargs(len(axis_names)))
+def _mesh(devices: np.ndarray, axis_names: tuple) -> Mesh:
+    return Mesh(devices, axis_names, axis_types=auto_axes(len(axis_names)))
 
 
 def shrink_mesh(mesh: Mesh, n_lost: int, model_axis: str = "model") -> Mesh:
@@ -36,10 +35,10 @@ def shrink_mesh(mesh: Mesh, n_lost: int, model_axis: str = "model") -> Mesh:
     other = tuple(n for n in names if n != model_axis)
     if len(other) == 1:
         shape = (rows, model) if names.index(model_axis) == 1 else (model, rows)
-        return _compat_mesh(flat.reshape(shape), names)
+        return _mesh(flat.reshape(shape), names)
     # collapse any extra leading axes (e.g. "pod") into the first data axis
     new_names = (other[-1], model_axis) if model_axis in names else other
-    return _compat_mesh(flat.reshape(rows, model), new_names)
+    return _mesh(flat.reshape(rows, model), new_names)
 
 
 def respec(sharding: NamedSharding, new_mesh: Mesh) -> NamedSharding:

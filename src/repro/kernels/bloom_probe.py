@@ -6,8 +6,11 @@ per-node Bloom filters. The filter rows are gathered once by the wrapper
 (h1 + i*h2) mod nbits, word selection by one-hot reduction over the W lane
 dimension (no in-row gather on TPU), and a bit test per probe.
 
-Block layout: (bb, W) uint32 filter rows + (bb, 1) key halves per tile; all
-buffers are VMEM-resident and lane-aligned for W in {8, 16, 32}.
+Block layout: (bb, W) filter rows + (bb, 1) key halves per tile; all
+buffers are VMEM-resident and lane-aligned for W in {8, 16, 32}. The rows
+enter as int32 bit patterns: the TPU lowering has no reduction over unsigned
+integers, and the one-hot word select sums a single non-zero word, so the
+int32 sum returns that word's bits unchanged.
 """
 from __future__ import annotations
 
@@ -33,21 +36,21 @@ def _hash32(lo, hi, seed: int):
 
 
 def _kernel(bits_ref, lo_ref, hi_ref, out_ref, *, k: int):
-    bits = bits_ref[...]                       # (bb, W) uint32
+    bits = bits_ref[...]                       # (bb, W) int32 bit patterns
     lo = lo_ref[...].astype(jnp.uint32)        # (bb, 1)
     hi = hi_ref[...].astype(jnp.uint32)
     w_iota = jax.lax.broadcasted_iota(jnp.int32, bits.shape, 1)
     nbits = bits.shape[1] * 32
-    h1 = _hash32(lo[:, 0], hi[:, 0], 0)
-    h2 = _hash32(lo[:, 0], hi[:, 0], 1) | jnp.uint32(1)
-    hit = jnp.ones(bits.shape[0], dtype=jnp.uint32)
+    h1 = _hash32(lo, hi, 0)
+    h2 = _hash32(lo, hi, 1) | jnp.uint32(1)
+    hit = jnp.ones(lo.shape, dtype=jnp.int32)
     for i in range(k):
         pos = (h1 + jnp.uint32(i) * h2) % jnp.uint32(nbits)
         w = (pos // 32).astype(jnp.int32)
-        shift = pos % 32
-        sel = jnp.sum(bits * (w_iota == w[:, None]).astype(jnp.uint32), axis=1)
-        hit = hit & ((sel >> shift) & jnp.uint32(1))
-    out_ref[...] = hit[:, None].astype(jnp.int32)
+        shift = (pos % 32).astype(jnp.int32)
+        sel = jnp.sum(jnp.where(w_iota == w, bits, 0), axis=1, keepdims=True)
+        hit = hit & (jax.lax.shift_right_logical(sel, shift) & 1)
+    out_ref[...] = hit
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bb", "interpret"))
@@ -60,7 +63,8 @@ def bloom_probe(bits: jnp.ndarray, key_lo: jnp.ndarray, key_hi: jnp.ndarray,
     """
     b, w = bits.shape
     bp = -(-b // bb) * bb
-    bits_p = jnp.pad(bits, ((0, bp - b), (0, 0)))
+    bits_p = jnp.pad(jax.lax.bitcast_convert_type(bits, jnp.int32),
+                     ((0, bp - b), (0, 0)))
     lo_p = jnp.pad(key_lo.astype(jnp.int32).reshape(-1, 1), ((0, bp - b), (0, 0)))
     hi_p = jnp.pad(key_hi.astype(jnp.int32).reshape(-1, 1), ((0, bp - b), (0, 0)))
     out = pl.pallas_call(

@@ -20,8 +20,12 @@ survivors overflowed the k-wide partial and recover them exactly (the
 streaming wrapper densifies just those rows, keeping the join lossless).
 
 The running-merge uses an iterative extract-max selection loop (max / where /
-iota / dynamic_update_slice only) rather than lax.top_k, so the kernel stays
-within Mosaic-supported primitives.
+iota only) rather than lax.top_k, so the kernel stays within Mosaic-supported
+primitives. The partial is kept `kp` = k rounded up to 128 lanes wide, so the
+concatenation of partial and tile stays lane-aligned; the lanes past k stay
+-inf / -1 and the wrapper slices them off. The driven side arrives transposed
+— (4, N) boxes and (1, N) keys / query ids — so each tile's driven columns
+are lane rows that broadcast against the (bm, 1) driver columns directly.
 """
 from __future__ import annotations
 
@@ -34,16 +38,19 @@ from jax.experimental import pallas as pl
 NEG_INF = float("-inf")
 
 
-def _select_topk(cat_s: jnp.ndarray, cat_i: jnp.ndarray, k: int
+def _select_topk(cat_s: jnp.ndarray, cat_i: jnp.ndarray, k: int, kp: int
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-row top-k of (bm, W) scores with aligned indices.
+    """Per-row top-k of (bm, W) scores with aligned indices, written into
+    the first k of `kp` output lanes (the rest stay -inf / -1).
 
     K-step extract-max: each step takes the row max, locates its first
     column (ties resolve to the lowest column, matching lax.top_k), records
-    (score, index), and masks the column out. Mosaic-safe ops only.
+    (score, index) in output lane t by an iota mask, and masks the column
+    out. Mosaic-safe ops only.
     """
     bm, w = cat_s.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (bm, w), 1)
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, (bm, kp), 1)
 
     def body(t, carry):
         cur_s, out_s, out_i = carry
@@ -53,13 +60,14 @@ def _select_topk(cat_s: jnp.ndarray, cat_i: jnp.ndarray, k: int
                        keepdims=True)                              # (bm, 1)
         sel = iota == pick                                         # one-hot
         idx = jnp.sum(jnp.where(sel, cat_i, 0), axis=1, keepdims=True)
-        out_s = jax.lax.dynamic_update_slice(out_s, m, (0, t))
-        out_i = jax.lax.dynamic_update_slice(out_i, idx, (0, t))
+        put = out_lane == t
+        out_s = jnp.where(put, m, out_s)
+        out_i = jnp.where(put, idx, out_i)
         cur_s = jnp.where(sel, NEG_INF, cur_s)
         return cur_s, out_s, out_i
 
-    out_s = jnp.full((bm, k), NEG_INF, dtype=cat_s.dtype)
-    out_i = jnp.full((bm, k), -1, dtype=jnp.int32)
+    out_s = jnp.full((bm, kp), NEG_INF, dtype=cat_s.dtype)
+    out_i = jnp.full((bm, kp), -1, dtype=jnp.int32)
     _, out_s, out_i = jax.lax.fori_loop(0, k, body, (cat_s, out_s, out_i))
     # padding steps re-pick masked (-inf) columns: scrub their stale indices
     out_i = jnp.where(out_s == NEG_INF, -1, out_i)
@@ -67,7 +75,7 @@ def _select_topk(cat_s: jnp.ndarray, cat_i: jnp.ndarray, k: int
 
 
 def _kernel(dist_ref, theta_ref, a_ref, ak_ref, aq_ref, b_ref, bk_ref,
-            bq_ref, s_ref, i_ref, c_ref, *, bn: int, k: int):
+            bq_ref, s_ref, i_ref, c_ref, *, bn: int, k: int, kp: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -77,28 +85,27 @@ def _kernel(dist_ref, theta_ref, a_ref, ak_ref, aq_ref, b_ref, bk_ref,
         c_ref[...] = jnp.zeros_like(c_ref)
 
     a = a_ref[...]                                  # (bm, 4) driver boxes
-    b = b_ref[...]                                  # (bn, 4) driven boxes
+    b = b_ref[...]                                  # (4, bn) driven boxes
     ax0, ay0, ax1, ay1 = (a[:, 0:1], a[:, 1:2], a[:, 2:3], a[:, 3:4])
-    bx0, by0, bx1, by1 = (b[:, 0].reshape(1, -1), b[:, 1].reshape(1, -1),
-                          b[:, 2].reshape(1, -1), b[:, 3].reshape(1, -1))
+    bx0, by0, bx1, by1 = (b[0:1, :], b[1:2, :], b[2:3, :], b[3:4, :])
     dx = jnp.maximum(0.0, jnp.maximum(ax0 - bx1, bx0 - ax1))
     dy = jnp.maximum(0.0, jnp.maximum(ay0 - by1, by0 - ay1))
     d = jnp.sqrt(dx * dx + dy * dy)                 # (bm, bn)
 
-    bound = ak_ref[...] + bk_ref[...][:, 0].reshape(1, -1)   # (bm, bn)
+    bound = ak_ref[...] + bk_ref[...]               # (bm, 1) + (1, bn)
     # per-ROW distance/theta (multi-query launches carry one per driver row)
     # and query-id masking: a pair only survives when driver and driven rows
     # belong to the same query
-    same_q = aq_ref[...] == bq_ref[...][:, 0].reshape(1, -1)  # (bm, bn)
+    same_q = aq_ref[...] == bq_ref[...]             # (bm, bn)
     valid = (d <= dist_ref[...]) & (bound > theta_ref[...]) & same_q
     col = (jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
            + j * bn)                                # global driven index
     tile_s = jnp.where(valid, bound, NEG_INF)
     tile_i = jnp.where(valid, col, -1)
 
-    cat_s = jnp.concatenate([s_ref[...], tile_s], axis=1)    # (bm, k + bn)
+    cat_s = jnp.concatenate([s_ref[...], tile_s], axis=1)    # (bm, kp + bn)
     cat_i = jnp.concatenate([i_ref[...], tile_i], axis=1)
-    top_s, top_i = _select_topk(cat_s, cat_i, k)
+    top_s, top_i = _select_topk(cat_s, cat_i, k, kp)
     s_ref[...] = top_s
     i_ref[...] = top_i
     c_ref[...] = c_ref[...] + jnp.sum(valid.astype(jnp.int32), axis=1,
@@ -135,14 +142,15 @@ def fused_topk_join(driver: jnp.ndarray, driven: jnp.ndarray,
     m, n = driver.shape[0], driven.shape[0]
     mp = -(-m // bm) * bm
     np_ = -(-n // bn) * bn
+    kp = -(-k // 128) * 128
     drv = jnp.pad(driver.astype(jnp.float32), ((0, mp - m), (0, 0)))
-    dvn = jnp.pad(driven.astype(jnp.float32), ((0, np_ - n), (0, 0)))
+    dvn = jnp.pad(driven.astype(jnp.float32), ((0, np_ - n), (0, 0))).T
     # padded driven columns carry a -inf key: bound = -inf is never > θ
     # (θ ≥ -inf), so padding can never appear among the survivors
     dk = jnp.pad(driver_keys.astype(jnp.float32), (0, mp - m),
                  constant_values=NEG_INF).reshape(-1, 1)
     vk = jnp.pad(driven_keys.astype(jnp.float32), (0, np_ - n),
-                 constant_values=NEG_INF).reshape(-1, 1)
+                 constant_values=NEG_INF).reshape(1, -1)
     # scalar dist/theta broadcast to per-row columns; padded rows keep their
     # -inf key, so their dist/theta values are irrelevant
     dist_arr = jnp.pad(jnp.broadcast_to(
@@ -158,10 +166,10 @@ def fused_topk_join(driver: jnp.ndarray, driven: jnp.ndarray,
     cq = (jnp.zeros(n, jnp.int32) if col_qid is None
           else col_qid.astype(jnp.int32))
     rq = jnp.pad(rq, (0, mp - m), constant_values=-1).reshape(-1, 1)
-    cq = jnp.pad(cq, (0, np_ - n), constant_values=-2).reshape(-1, 1)
+    cq = jnp.pad(cq, (0, np_ - n), constant_values=-2).reshape(1, -1)
     grid = (mp // bm, np_ // bn)
     scores, idx, counts = pl.pallas_call(
-        functools.partial(_kernel, bn=bn, k=k),
+        functools.partial(_kernel, bn=bn, k=k, kp=kp),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
@@ -169,20 +177,20 @@ def fused_topk_join(driver: jnp.ndarray, driven: jnp.ndarray,
             pl.BlockSpec((bm, 4), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 4), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((4, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp, k), jnp.float32),
-            jax.ShapeDtypeStruct((mp, k), jnp.int32),
+            jax.ShapeDtypeStruct((mp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((mp, kp), jnp.int32),
             jax.ShapeDtypeStruct((mp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(dist_arr, theta_arr, drv, dk, rq, dvn, vk, cq)
-    return scores[:m], idx[:m], counts[:m, 0]
+    return scores[:m, :k], idx[:m, :k], counts[:m, 0]

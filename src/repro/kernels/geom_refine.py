@@ -17,9 +17,13 @@ hoisted to pool build time and the monotone final transform
 
 Padding replicates a real point of the same entity (every pool row holds at
 least one point), so duplicated points can never change the minimum and the
-kernel needs no validity masks. Per block row the kernel walks the m_pad
-driver points with a fori_loop, broadcasting each against all n_pad driven
-points on the VPU, and keeps the running minimum of the squared distance.
+kernel needs no validity masks. The kernel sees the planes transposed —
+points on the sublane axis, pairs on the lane axis — so a fori_loop over the
+m_pad driver points reads each as one (1, bb) sublane row, broadcasts it
+against the (n_pad, bb) driven points on the VPU, and keeps an elementwise
+running minimum; one sublane reduction at the end gives the lane-dense
+(1, bb) result. The min of a set does not depend on the order it is taken
+in, so the result equals the loop order of the host twin bit for bit.
 """
 from __future__ import annotations
 
@@ -33,20 +37,20 @@ POS_INF = float("inf")
 
 
 def _kernel(*refs, m_pad: int, dims: int):
-    a = [r[...] for r in refs[:dims]]               # dims x (bb, m_pad)
-    b = [r[...] for r in refs[dims:2 * dims]]       # dims x (bb, n_pad)
-    out_ref = refs[2 * dims]
+    a_refs = refs[:dims]                            # dims x (m_pad, bb)
+    b = [r[...] for r in refs[dims:2 * dims]]       # dims x (n_pad, bb)
+    out_ref = refs[2 * dims]                        # (1, bb)
 
     def body(i, best):
         v = None
-        for ad, bd in zip(a, b):
-            ai = jax.lax.dynamic_slice_in_dim(ad, i, 1, axis=1)  # (bb, 1)
-            d = ai - bd
+        for ar, bd in zip(a_refs, b):
+            d = ar[pl.ds(i, 1), :] - bd             # (1, bb) - (n_pad, bb)
             v = d * d if v is None else v + d * d
-        return jnp.minimum(best, jnp.min(v, axis=1, keepdims=True))
+        return jnp.minimum(best, v)
 
-    init = jnp.full(out_ref.shape, POS_INF, dtype=out_ref.dtype)
-    out_ref[...] = jax.lax.fori_loop(0, m_pad, body, init)
+    init = jnp.full(b[0].shape, POS_INF, dtype=jnp.float32)
+    best = jax.lax.fori_loop(0, m_pad, body, init)
+    out_ref[...] = jnp.min(best, axis=0, keepdims=True)
 
 
 @jax.jit
@@ -83,15 +87,15 @@ def bucketed_min_core(a_planes: tuple, b_planes: tuple,
     m, m_pad = a_planes[0].shape
     n_pad = b_planes[0].shape[1]
     bp = -(-m // bb) * bb
-    tiles = [jnp.pad(t.astype(jnp.float32), ((0, bp - m), (0, 0)))
+    tiles = [jnp.pad(t.astype(jnp.float32), ((0, bp - m), (0, 0))).T
              for t in (*a_planes, *b_planes)]
     raw = pl.pallas_call(
         functools.partial(_kernel, m_pad=m_pad, dims=dims),
         grid=(bp // bb,),
-        in_specs=([pl.BlockSpec((bb, m_pad), lambda i: (i, 0))] * dims
-                  + [pl.BlockSpec((bb, n_pad), lambda i: (i, 0))] * dims),
-        out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bp, 1), jnp.float32),
+        in_specs=([pl.BlockSpec((m_pad, bb), lambda i: (0, i))] * dims
+                  + [pl.BlockSpec((n_pad, bb), lambda i: (0, i))] * dims),
+        out_specs=pl.BlockSpec((1, bb), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, bp), jnp.float32),
         interpret=interpret,
     )(*tiles)
-    return raw[:m, 0]
+    return raw[0, :m]

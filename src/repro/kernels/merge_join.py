@@ -105,7 +105,7 @@ def _kernel(n_tiles: int, tn: int,
 @functools.partial(jax.jit, static_argnames=("bb", "tn", "interpret"))
 def merge_join_ranks(t_hi: jnp.ndarray, t_lo: jnp.ndarray,
                      p_hi: jnp.ndarray, p_lo: jnp.ndarray,
-                     bb: int = 1024, tn: int = 8192,
+                     bb: int = 512, tn: int = 2048,
                      interpret: bool = False
                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Counting rank pass over one probe batch.
@@ -113,7 +113,9 @@ def merge_join_ranks(t_hi: jnp.ndarray, t_lo: jnp.ndarray,
     t_* (N,) / p_* (M,) int32 planes of sorted table keys / probe keys
     (see `ops.split_key_planes`; table sorted by the underlying int64).
     `tn` bounds the VMEM-resident table tile (lane-rounded, clamped to the
-    padded table size so small tables stay single-tile).
+    padded table size so small tables stay single-tile). The defaults keep
+    each (bb, tn) int32 compare mask at 4 MiB, inside the 16 MiB of scoped
+    VMEM a v5e kernel gets by default.
     Returns (lo (M,), hi (M,)) int32 insertion ranks.
     """
     m = p_hi.shape[0]
@@ -132,7 +134,7 @@ def merge_join_ranks(t_hi: jnp.ndarray, t_lo: jnp.ndarray,
         functools.partial(_kernel, n_pad // tn, tn),
         grid=(mp // bb,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),          # table: HBM
+            pl.BlockSpec(memory_space=pl.ANY),             # table: HBM
             pl.BlockSpec((bb, 1), lambda i: (i, 0)),
             pl.BlockSpec((bb, 1), lambda i: (i, 0)),
         ],

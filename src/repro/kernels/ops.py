@@ -389,27 +389,12 @@ def tree_descend_sharded(node_keys, cs_path, box_keys,
 
     def via_shard_map():
         from ..launch import mesh as _mesh
-        msh = _mesh.make_shard_mesh(s)
-        spec = jax.sharding.PartitionSpec
         n_hi, n_lo = split_key_planes(node_keys)
         b_hi, b_lo = split_key_planes(padded)
-        pallas = backend == "interpret" or _on_tpu()
-
-        def body(nh, nl, c, bh, bl):
-            def one(args):
-                nh1, nl1, c1 = args
-                if pallas:
-                    return _td.tree_descend(
-                        nh1, nl1, c1, bh, bl,
-                        interpret=backend == "interpret" and not _on_tpu())
-                return ref.tree_descend_ref(nh1, nl1, c1, bh, bl)
-            return jax.lax.map(one, (nh, nl, c))
-
-        f = _mesh.shard_map_compat(
-            body, msh,
-            in_specs=(spec("shard"), spec("shard"), spec("shard"),
-                      spec(), spec()),
-            out_specs=spec("shard"))
+        f = sharded_descend_fn(_mesh.make_shard_mesh(s),
+                               pallas=backend == "interpret" or _on_tpu(),
+                               interpret=backend == "interpret"
+                               and not _on_tpu())
         out = f(jnp.asarray(n_hi), jnp.asarray(n_lo), jnp.asarray(cs),
                 jnp.asarray(b_hi), jnp.asarray(b_lo))
         return np.asarray(out)[:, :b]
@@ -422,6 +407,31 @@ def tree_descend_sharded(node_keys, cs_path, box_keys,
     attempts = [("shard_map", via_shard_map), ("sequential", sequential)]
     out = _fault.run_op("tree_descend_sharded", attempts, validate=_v_mask01)
     return np.asarray(out) != 0
+
+
+@functools.lru_cache(maxsize=None)
+def sharded_descend_fn(mesh, pallas: bool, interpret: bool = False):
+    """The jitted shard_map program behind `tree_descend_sharded`: the
+    stacked (S, ...) node planes and cs masks split over the mesh's "shard"
+    axis, the driver boxes replicated, and each device sweeping its resident
+    shards with `lax.map` over the per-shard descent — the Pallas kernel
+    when `pallas`, else the dense jnp oracle. Cached per (mesh, route), so a
+    serve loop compiles it once per shape and not once per call."""
+    spec = jax.sharding.PartitionSpec
+
+    def body(nh, nl, c, bh, bl):
+        def one(args):
+            nh1, nl1, c1 = args
+            if pallas:
+                return _td.tree_descend(nh1, nl1, c1, bh, bl,
+                                        interpret=interpret)
+            return ref.tree_descend_ref(nh1, nl1, c1, bh, bl)
+        return jax.lax.map(one, (nh, nl, c))
+
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(spec("shard"), spec("shard"), spec("shard"), spec(), spec()),
+        out_specs=spec("shard"), check_vma=False))
 
 
 def _v_mask01(out) -> bool:

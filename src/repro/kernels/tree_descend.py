@@ -27,7 +27,9 @@ the same plane trick the merge-join rank kernel uses for its int64 keys.
 Grid: (blocks, node tiles, box tiles); each (1, nt) node-tile output row
 is an accumulator revisited across the box-tile axis (zeroed on the first
 tile via `pl.when`), OR-ing in each box tile's hit-any reduction, so one
-(bm-box, nt-node) tile pair is VMEM resident at a time. Node lanes padded
+(bm-box, nt-node) tile pair is VMEM resident at a time. The output is laid
+out (B, 1, N) so that a one-row block spans the whole sublane axis, which
+the TPU's (8, 128) block tiling requires. Node lanes padded
 past N carry cs = 0; box rows padded past M carry the never-intersecting
 sentinel box (mins at the key maximum, maxs at the key minimum — real
 keys live strictly inside the int64 range, see `ops.f64_sort_keys`).
@@ -114,8 +116,8 @@ def tree_descend(nodes_hi: jnp.ndarray, nodes_lo: jnp.ndarray,
         _kernel,
         grid=(b, n_pad // nt, mt),
         in_specs=[node_spec] * 8 + [box_spec] * 8 + [node_spec],
-        out_specs=pl.BlockSpec((1, nt), lambda bb, t, j: (bb, t)),
-        out_shape=jax.ShapeDtypeStruct((b, n_pad), jnp.int32),
+        out_specs=pl.BlockSpec((None, 1, nt), lambda bb, t, j: (bb, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, n_pad), jnp.int32),
         interpret=interpret,
     )(*node_in, *box_in, cs)
-    return out[:, :n]
+    return out[:, 0, :n]

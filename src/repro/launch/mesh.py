@@ -13,17 +13,15 @@ from __future__ import annotations
 import jax
 
 
-def _axis_kwargs(n_axes: int) -> dict:
-    """axis_types only exists on newer jax; omit it where absent."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+def auto_axes(n_axes: int) -> tuple:
+    """`axis_types` for a mesh whose axes are all Auto-sharded."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -32,7 +30,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     data = min(data, n)
     model = max(1, min(model, n // data))
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_kwargs(2))
+                         axis_types=auto_axes(2))
 
 
 def make_shard_mesh(n_shards: int):
@@ -45,14 +43,4 @@ def make_shard_mesh(n_shards: int):
     """
     n = len(jax.devices())
     d = max(k for k in range(1, min(n, n_shards) + 1) if n_shards % k == 0)
-    return jax.make_mesh((d,), ("shard",), **_axis_kwargs(1))
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across versions (older jax: experimental, check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.make_mesh((d,), ("shard",), axis_types=auto_axes(1))

@@ -59,9 +59,11 @@ class ServeEngine:
     def _advance_slot(self, slot: int, token: int) -> int:
         tokens = np.zeros(self.max_batch, dtype=np.int32)
         tokens[slot] = token
+        # a copy of pos: the step is dispatched asynchronously and may alias
+        # a host buffer on the CPU, and pos is mutated right after dispatch
         logits, self.cache = self._step(
             self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(self.pos))
+            jnp.asarray(self.pos.copy()))
         self.pos[slot] += 1
         return int(jnp.argmax(logits[slot]))
 
@@ -77,7 +79,7 @@ class ServeEngine:
             tokens[s] = self.slot_req[s]._next
         logits, self.cache = self._step(
             self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(self.pos))
+            jnp.asarray(self.pos.copy()))
         nxt = np.asarray(jnp.argmax(logits, axis=-1))
         for s in active:
             req = self.slot_req[s]

@@ -23,12 +23,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across versions; see launch/mesh.shard_map_compat."""
-    from ..launch.mesh import shard_map_compat
-    return shard_map_compat(f, mesh, in_specs, out_specs)
-
-
 def _merge_topk(scores, ids, new_scores, new_ids, k):
     s = jnp.concatenate([scores, new_scores], axis=-1)
     i = jnp.concatenate([ids, new_ids], axis=-1)
@@ -149,10 +143,7 @@ def streak_topk_sharded(state, items_sorted, item_order, bounds,
     def local(state_, items_, order_, bounds_):
         # mark the (replicated) query state shard-varying so the while-loop
         # carry typing matches the shard-local block scan
-        if hasattr(jax.lax, "pcast"):
-            state_ = jax.lax.pcast(state_, (axis,), to="varying")
-        else:  # zero-valued data dependency on a shard-local array
-            state_ = state_ + 0.0 * items_.ravel()[0]
+        state_ = jax.lax.pcast(state_, (axis,), to="varying")
         scores, ids, bi = streak_topk(state_, items_, order_, bounds_,
                                       k=k, block=block)
         all_s = jax.lax.all_gather(scores, axis, axis=1)   # (B, n, k)
@@ -164,10 +155,11 @@ def streak_topk_sharded(state, items_sorted, item_order, bounds,
 
     # replication checks off: outputs ARE replicated (all_gather +
     # deterministic top_k) but the varying-axis inference cannot prove it
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis, None), P(axis), P(axis)),
-        out_specs=(P(), P(), P()))(state, items_sorted, item_order, bounds)
+        out_specs=(P(), P(), P()), check_vma=False)(
+            state, items_sorted, item_order, bounds)
 
 
 def blocked_topk_sharded(state, items, mesh, axis: str = "model",
@@ -181,10 +173,7 @@ def blocked_topk_sharded(state, items, mesh, axis: str = "model",
     base = jnp.arange(0, n, n // shards, dtype=jnp.int32)[:shards]
 
     def local(state_, items_, offset_):
-        if hasattr(jax.lax, "pcast"):
-            state_ = jax.lax.pcast(state_, (axis,), to="varying")
-        else:
-            state_ = state_ + 0.0 * items_.ravel()[0]
+        state_ = jax.lax.pcast(state_, (axis,), to="varying")
         scores, ids = blocked_topk(state_, items_, k=k,
                                    block=min(block, items_.shape[0]))
         ids = ids + offset_[0]
@@ -195,7 +184,7 @@ def blocked_topk_sharded(state, items, mesh, axis: str = "model",
         top_i = jnp.take_along_axis(all_i.reshape(b, -1), pos, axis=-1)
         return top_s, top_i
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis, None), P(axis)),
-        out_specs=(P(), P()))(state, items, base)
+        out_specs=(P(), P()), check_vma=False)(state, items, base)

@@ -9,6 +9,7 @@ results whose θ-derived `score_bound` certifiably dominates everything it
 left out (verified against the full-scan oracle).
 """
 import dataclasses
+import logging
 import time
 
 import numpy as np
@@ -75,6 +76,46 @@ def test_each_op_failing_once_is_bit_identical(lgd, op, policy):
     assert plan.injected > 0, f"{op} was never dispatched under {policy}"
     assert fault.STATE.stats.fallbacks > 0
     _assert_same(got, want)
+
+
+def _fault_warnings(caplog) -> list[str]:
+    return [r.getMessage() for r in caplog.records
+            if r.name == fault.__name__ and r.levelno == logging.WARNING]
+
+
+def test_failing_backend_warns_once_per_op_and_backend(lgd, caplog):
+    """A backend that fails is logged on its first failure, naming the op,
+    the backend and the exception; repeats of that (op, backend) stay quiet
+    and every other (op, backend) gets its own warning."""
+    q = lgd.queries[0]
+    pol = BackendPolicy(descend="kernel", probe="kernel")
+    plan = FaultPlan(rules=(FaultRule(op="tree_descend"),
+                            FaultRule(op="bloom_probe")))
+    with caplog.at_level(logging.WARNING, logger=fault.__name__), \
+            fault.fault_plan(plan):
+        _run(lgd, q, policy=pol)
+        _run(lgd, q, policy=pol)
+    assert plan.calls["tree_descend"] >= 2 and plan.calls["bloom_probe"] >= 2
+    warned = _fault_warnings(caplog)
+    assert len(warned) == 2, warned
+    # the CPU's live routes: the descent's jitted oracle is its "kernel"
+    # backend, the probe's jnp twin its "jit" backend
+    for op, backend in (("tree_descend", "kernel"), ("bloom_probe", "jit")):
+        [msg] = [m for m in warned if m.startswith(op)]
+        assert f"{backend!r}" in msg and "InjectedFault" in msg
+
+
+def test_clean_run_logs_nothing_and_counts_routes(lgd, caplog):
+    q = lgd.queries[0]
+    pol = BackendPolicy(join="fused", descend="kernel", probe="kernel")
+    with caplog.at_level(logging.WARNING, logger=fault.__name__):
+        _run(lgd, q, policy=pol)
+    assert _fault_warnings(caplog) == []
+    st = fault.STATE.stats
+    assert st.failures == 0 and st.fallbacks == 0
+    assert st.calls[("fused_topk_join", "jit")] > 0
+    assert st.calls[("tree_descend", "kernel")] > 0
+    assert st.calls[("bloom_probe", "jit")] > 0
 
 
 def test_seeded_random_failure_rate_is_bit_identical(lgd):

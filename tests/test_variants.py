@@ -70,7 +70,6 @@ def test_microbatch_accumulation_matches_full_batch_grads():
 def test_streak_topk_sharded_matches_unsharded():
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    # plain make_mesh: jax.sharding.AxisType is absent in the pinned jax
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh(1, 1)
     rng = np.random.default_rng(0)
