@@ -37,8 +37,7 @@ independent of completions, so queueing delay is part of the measured
 latency. Per load it reports mean and p50/p95/p99 latency — the 1.2x row
 shows the queue growing (p99 >> p50), the 0.5x row the uncongested floor.
 
-Standalone: ``python -m benchmarks.bench_serve --json`` writes
-``BENCH_serve.json`` (the artifact CI uploads).
+Standalone: ``python -m benchmarks.bench_serve`` prints the rows.
 """
 from __future__ import annotations
 
@@ -209,19 +208,9 @@ def openloop(ds) -> list:
 
 
 def main() -> None:
-    import json
-    import sys
     print("name,us_per_call,derived")
-    out = []
     for r in run():
         print(r)
-        name, us, derived = r.split(",", 2)
-        out.append({"name": name, "us_per_call": float(us),
-                    "derived": derived})
-    if "--json" in sys.argv[1:]:
-        with open("BENCH_serve.json", "w") as fh:
-            json.dump(out, fh, indent=1)
-        print("# wrote BENCH_serve.json", file=sys.stderr)
 
 
 if __name__ == "__main__":

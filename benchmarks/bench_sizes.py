@@ -7,8 +7,7 @@ tier (`PackedEList`) against the uncompressed tier, and per-query engine
 latency unsharded vs 4-way sharded — with the sharded results asserted
 identical to the unsharded engine before anything is timed.
 
-Default sizes stop at 10M so the committed BENCH_sizes.json stays
-reproducible in CI-class time; set ``REPRO_BENCH_SIZES`` (comma-separated
+Default sizes stop at 10M so a run stays within CI-class time; set ``REPRO_BENCH_SIZES`` (comma-separated
 quad counts, e.g. ``1000000,100000000``) to sweep the full curve.
 """
 from __future__ import annotations

@@ -8,13 +8,14 @@ fires when the best possible remaining score key cannot beat theta.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import warnings
 from typing import Callable
 
 import numpy as np
 
-from . import aps, node_select, shard as shard_mod, spatial_join
+from . import aps, node_select, shard as shard_mod, spatial_join, spans
 from .join import Relation, filter_in_ranges, join, scan_pattern
 from .planner import QueryPlan, SidePlan, plan_query
 from .policy import BackendPolicy
@@ -103,6 +104,43 @@ class ExecStats:
     plan_log: list = dataclasses.field(default_factory=list)
 
 
+class ShareCache:
+    """Cross-tenant memo of per-block results (serve mode), keyed by kind
+    and key, bounded by insertion-order eviction (`trim`). It counts the
+    lookups and hits of each kind and the entries evicted."""
+
+    def __init__(self, max_entries: int | None = None):
+        self.entries: dict = {}
+        self.max_entries = max_entries
+        self.lookups: collections.Counter = collections.Counter()
+        self.hits: collections.Counter = collections.Counter()
+        self.evictions = 0
+
+    def __len__(self) -> int:
+        return len(self.entries)
+
+    def get(self, kind: str, key: tuple, compute: Callable):
+        """The entry under (kind, *key); `compute()` stored there first
+        when it is missing."""
+        self.lookups[kind] += 1
+        full = (kind,) + key
+        if full in self.entries:
+            self.hits[kind] += 1
+            return self.entries[full]
+        out = self.entries[full] = compute()
+        return out
+
+    def trim(self) -> None:
+        """Drop the oldest entries past `max_entries`: dicts iterate
+        oldest-first, so the stalest per-block results go while the newest
+        (this step's hot entries) stay."""
+        if self.max_entries is None:
+            return
+        while len(self.entries) > self.max_entries:
+            self.entries.pop(next(iter(self.entries)))
+            self.evictions += 1
+
+
 class StreakEngine:
     def __init__(self, store: QuadStore, config: ExecConfig | None = None):
         self.store = store
@@ -113,14 +151,23 @@ class StreakEngine:
         self.kcap_tuner = (spatial_join.KcapTuner()
                            if self.config.policy.kcap == "auto" else None)
         # cross-tenant work sharing (serve mode): the serving layer sets
-        # this to a dict, and per-block sub-results that are PURE functions
-        # of (side signature, block) or (side signature, SIP intervals) —
-        # driver-block materialization, S-Plan filtered retrieval, N-Plan
-        # per-block joins — are memoized so concurrent tenants running the
-        # same query shape (e.g. different k) compute them once.
+        # this to a ShareCache, and per-block sub-results that are PURE
+        # functions of their key — driver-block materialization, S-Plan
+        # filtered retrieval, N-Plan per-block joins, MBR pairs, refine
+        # verdicts — are memoized (`shared`) so concurrent tenants running
+        # the same query shape (e.g. different k) compute them once.
         # θ-dependent work (guards, APS key_needed, N-Plan truncation,
         # TopK) stays per-tenant, so shared results are bit-identical.
-        self.share_cache: dict | None = None
+        self.share_cache: ShareCache | None = None
+
+    def shared(self, kind: str, key: Callable, compute: Callable):
+        """`compute()`, memoized across tenants under `kind` and `key()`
+        when the share cache is on (`key` is called only then). A hit skips
+        whatever counters `compute` would bump: they count work done."""
+        sc = self.share_cache
+        if sc is None:
+            return compute()
+        return sc.get(kind, key(), compute)
 
     @staticmethod
     def _side_sig(side: SidePlan, plan: QueryPlan) -> tuple:
@@ -226,7 +273,7 @@ class StreakEngine:
                     driver: SidePlan, driven: SidePlan, plan: QueryPlan,
                     topk: TopK, stats: ExecStats,
                     ds: np.ndarray | None = None,
-                    vs: np.ndarray | None = None) -> None:
+                    vs: np.ndarray | None = None, rid=None) -> None:
         """θ-aware refinement: order pairs by key bound, refine in chunks.
 
         Candidate pairs are sorted by descending score-key bound
@@ -240,58 +287,56 @@ class StreakEngine:
         """
         if len(pi) == 0:
             return
-        store = self.store
-        if ds is None:
-            ds = self._entity_key_bound(drv_rel, uniq_ents, driver, plan)
-        if vs is None:
-            vs = self._entity_key_bound(dvn_rel, dvn_ents, driven, plan)
-        bounds = ds[pi] + vs[pj]
-        order = np.argsort(-bounds, kind="stable")
-        pi, pj, bounds = pi[order], pj[order], bounds[order]
-        # resolve pool rows once per unique entity, gather per pair
-        rows_a = store.geom_rows(uniq_ents)[pi]
-        rows_b = store.geom_rows(dvn_ents)[pj]
-        chunk = max(int(self.config.refine_chunk), 1)
-        for start in range(0, len(pi), chunk):
-            # bounds are sorted: bounds[start] caps every remaining pair
-            if topk.full and bounds[start] <= topk.theta:
-                stats.join.refine_skipped += len(pi) - start
-                break
-            end = min(start + chunk, len(pi))
-            # exact-geometry chunk verdicts are pure in (pool rows,
-            # distance, metric); same-shape tenants chunk identically
-            # (same pairs, same bound order), so serve mode shares them
-            sc = self.share_cache
-            rkey = None
-            if sc is not None:
-                rkey = ("refine", plan.metric, float(plan.dist_world),
-                        rows_a[start:end].tobytes(),
-                        rows_b[start:end].tobytes())
-            if rkey is not None and rkey in sc:
-                keep = sc[rkey]
-            else:
-                keep = spatial_join.refine(
-                    pi[start:end], pj[start:end], store.geom_pool,
-                    rows_a[start:end], rows_b[start:end],
-                    plan.dist_world, plan.metric, stats.join)
-                if rkey is not None:
-                    sc[rkey] = keep
-            ci, cj = pi[start:end][keep], pj[start:end][keep]
-            if len(ci) == 0:
-                continue
-            pair_rel = Relation({driver.entity_var: uniq_ents[ci],
-                                 driven.entity_var: dvn_ents[cj]})
-            out = join(drv_rel, pair_rel, impl=plan.join_impl,
-                       backend=plan.rank_backend)
-            out = join(out, dvn_rel, impl=plan.join_impl,
-                       backend=plan.rank_backend)
-            if out.n == 0:
-                continue
-            keys = self._score_key(out, plan)
-            valid = ~np.isnan(keys)
-            out, keys = out.take(np.flatnonzero(valid)), keys[valid]
-            stats.results_considered += out.n
-            topk.push(keys, out)
+        with spans.span("streak.topk", rid=rid):
+            store = self.store
+            if ds is None:
+                ds = self._entity_key_bound(drv_rel, uniq_ents, driver, plan)
+            if vs is None:
+                vs = self._entity_key_bound(dvn_rel, dvn_ents, driven, plan)
+            bounds = ds[pi] + vs[pj]
+            order = np.argsort(-bounds, kind="stable")
+            pi, pj, bounds = pi[order], pj[order], bounds[order]
+            # resolve pool rows once per unique entity, gather per pair
+            rows_a = store.geom_rows(uniq_ents)[pi]
+            rows_b = store.geom_rows(dvn_ents)[pj]
+            chunk = max(int(self.config.refine_chunk), 1)
+            for start in range(0, len(pi), chunk):
+                # bounds are sorted: bounds[start] caps every remaining pair
+                if topk.full and bounds[start] <= topk.theta:
+                    stats.join.refine_skipped += len(pi) - start
+                    break
+                end = min(start + chunk, len(pi))
+
+                def refine(start=start, end=end):
+                    with spans.span("streak.refine", rid=rid):
+                        return spatial_join.refine(
+                            pi[start:end], pj[start:end], store.geom_pool,
+                            rows_a[start:end], rows_b[start:end],
+                            plan.dist_world, plan.metric, stats.join)
+
+                # exact-geometry chunk verdicts are pure in (pool rows,
+                # distance, metric); same-shape tenants chunk identically
+                # (same pairs, same bound order), so serve mode shares them
+                keep = self.shared(
+                    "refine", lambda: (plan.metric, float(plan.dist_world),
+                                       rows_a[start:end].tobytes(),
+                                       rows_b[start:end].tobytes()), refine)
+                ci, cj = pi[start:end][keep], pj[start:end][keep]
+                if len(ci) == 0:
+                    continue
+                pair_rel = Relation({driver.entity_var: uniq_ents[ci],
+                                     driven.entity_var: dvn_ents[cj]})
+                out = join(drv_rel, pair_rel, impl=plan.join_impl,
+                           backend=plan.rank_backend)
+                out = join(out, dvn_rel, impl=plan.join_impl,
+                           backend=plan.rank_backend)
+                if out.n == 0:
+                    continue
+                keys = self._score_key(out, plan)
+                valid = ~np.isnan(keys)
+                out, keys = out.take(np.flatnonzero(valid)), keys[valid]
+                stats.results_considered += out.n
+                topk.push(keys, out)
 
     # ------------------------------------------------------------------
     def execute(self, q: Query, deadline=None
@@ -301,15 +346,16 @@ class StreakEngine:
             cur.step()
         return cur.results()
 
-    def cursor(self, q: Query, deadline=None):
+    def cursor(self, q: Query, deadline=None, rid=None):
         """Steppable execution state (one driver block per step) for the
-        multi-tenant serving loop (serve/spatial.py). Non-top-k shapes
-        (range / within / kNN / spatial join, core/shapes.py) return a
-        `ShapeCursor` speaking the same protocol."""
+        multi-tenant serving loop (serve/spatial.py); `rid` names the
+        request on the cursor's spans. Non-top-k shapes (range / within /
+        kNN / spatial join, core/shapes.py) return a `ShapeCursor` speaking
+        the same protocol."""
         if q.spatial is not None and q.shape() != "topk":
             from .shapes import ShapeCursor
             return ShapeCursor(self, q, deadline=deadline)
-        return QueryCursor(self, q, deadline=deadline)
+        return QueryCursor(self, q, deadline=deadline, rid=rid)
 
     # ------------------------------------------------------------------
     def _driven_full(self, driven: SidePlan, impl: str | None,
@@ -330,21 +376,16 @@ class StreakEngine:
                       explicit, stats: ExecStats) -> Relation:
         """S-Plan: spatial join pushed down -- one full scan of the driven
         sub-query (cached), then I-Range/E-list skipping of its rows."""
-        rel = self._driven_full(driven, plan.join_impl, plan.rank_backend)
-        stats.driven_rows_scanned += rel.n
-        if self.config.use_sip and driven.entity_var in rel:
-            sc, key = self.share_cache, None
-            if sc is not None:
-                key = ("splan", self._side_sig(driven, plan),
-                       intervals.tobytes(), explicit.tobytes())
-            if key is not None and key in sc:
-                rel = sc[key]
-            else:
-                rel = filter_in_ranges(rel, driven.entity_var, intervals,
-                                       explicit, impl=plan.join_impl,
-                                       backend=plan.rank_backend)
-                if key is not None:
-                    sc[key] = rel
+        full = self._driven_full(driven, plan.join_impl, plan.rank_backend)
+        stats.driven_rows_scanned += full.n
+        rel = full
+        if self.config.use_sip and driven.entity_var in full:
+            rel = self.shared(
+                "splan", lambda: (self._side_sig(driven, plan),
+                                  intervals.tobytes(), explicit.tobytes()),
+                lambda: filter_in_ranges(full, driven.entity_var, intervals,
+                                         explicit, impl=plan.join_impl,
+                                         backend=plan.rank_backend))
         stats.driven_rows_after_sip += rel.n
         return rel
 
@@ -352,44 +393,20 @@ class StreakEngine:
                       explicit, key_needed: float, stats: ExecStats) -> Relation:
         """N-Plan: numeric predicate pushed down -- block-wise driven scan in
         score-key order with SIP skipping and threshold early termination."""
-        cfg = self.config
         parts: list[Relation] = []
         kw = self._kw(driven.primary[2], plan.descending)
-        sc = self.share_cache
-        sig = self._side_sig(driven, plan) if sc is not None else None
         for b2 in range(driven.scan.n_blocks):
             best = kw * float(driven.scan.get_block(b2)[0][0])
             if np.isfinite(key_needed) and best <= key_needed:
                 break  # no further driven block can reach the threshold
             # the per-block retrieval is θ-independent (only the truncation
             # above is), so concurrent same-shape tenants share it
-            key = None
-            if sc is not None:
-                key = ("nblk", sig, b2, intervals.tobytes(),
-                       explicit.tobytes())
-            if key is not None and key in sc:
-                scanned, joined = sc[key]
-                stats.driven_rows_scanned += scanned
-            else:
-                block_rel, _ = self._block_relation(driven, b2)
-                scanned = block_rel.n
-                stats.driven_rows_scanned += scanned
-                if cfg.use_sip and driven.entity_var in block_rel:
-                    block_rel = filter_in_ranges(block_rel,
-                                                 driven.entity_var,
-                                                 intervals, explicit,
-                                                 impl=plan.join_impl,
-                                                 backend=plan.rank_backend)
-                joined = self._join_chain(block_rel, driven.join_patterns,
-                                          plan.join_impl, plan.rank_backend)
-                if cfg.use_sip and driven.entity_var not in block_rel \
-                        and driven.entity_var in joined:
-                    joined = filter_in_ranges(joined, driven.entity_var,
-                                              intervals, explicit,
-                                              impl=plan.join_impl,
-                                              backend=plan.rank_backend)
-                if key is not None:
-                    sc[key] = (scanned, joined)
+            scanned, joined = self.shared(
+                "nblk", lambda: (self._side_sig(driven, plan), b2,
+                                 intervals.tobytes(), explicit.tobytes()),
+                lambda: self._nplan_block(driven, plan, b2, intervals,
+                                          explicit))
+            stats.driven_rows_scanned += scanned
             stats.driven_rows_after_sip += joined.n
             if joined.n:
                 parts.append(joined)
@@ -397,6 +414,26 @@ class StreakEngine:
             return Relation()
         cols = parts[0].keys()
         return Relation({c: np.concatenate([p[c] for p in parts]) for c in cols})
+
+    def _nplan_block(self, driven: SidePlan, plan: QueryPlan, b2: int,
+                     intervals, explicit) -> tuple[int, Relation]:
+        """(rows scanned, SIP-filtered joined rows) of driven block `b2`."""
+        use_sip = self.config.use_sip
+        block_rel, _ = self._block_relation(driven, b2)
+        scanned = block_rel.n
+        if use_sip and driven.entity_var in block_rel:
+            block_rel = filter_in_ranges(block_rel, driven.entity_var,
+                                         intervals, explicit,
+                                         impl=plan.join_impl,
+                                         backend=plan.rank_backend)
+        joined = self._join_chain(block_rel, driven.join_patterns,
+                                  plan.join_impl, plan.rank_backend)
+        if use_sip and driven.entity_var not in block_rel \
+                and driven.entity_var in joined:
+            joined = filter_in_ranges(joined, driven.entity_var, intervals,
+                                      explicit, impl=plan.join_impl,
+                                      backend=plan.rank_backend)
+        return scanned, joined
 
 
 class QueryCursor:
@@ -418,9 +455,11 @@ class QueryCursor:
     blocks from different queries interleave.
     """
 
-    def __init__(self, engine: StreakEngine, q: Query, deadline=None):
+    def __init__(self, engine: StreakEngine, q: Query, deadline=None,
+                 rid=None):
         self.engine = engine
         self.deadline = deadline            # core/fault.QueryDeadline | None
+        self.rid = rid                      # the served request, on spans
         cfg = engine.config
         store = engine.store
         self.tree = store.tree
@@ -520,11 +559,11 @@ class QueryCursor:
 
     def _materialize(self, w: int) -> tuple:
         """(drv_rel, uniq_ents, boxes) for driver block `w`."""
+        return self.engine.shared("mat", lambda: (self._drv_sig, w),
+                                  lambda: self._materialize_fresh(w))
+
+    def _materialize_fresh(self, w: int) -> tuple:
         eng, plan, driver = self.engine, self.plan, self.driver
-        sc = eng.share_cache
-        key = ("mat", self._drv_sig, w) if sc is not None else None
-        if key is not None and key in sc:
-            return sc[key]
         if driver.scan is not None:
             block_rel, _ = eng._block_relation(driver, w)
             join_chain = driver.join_patterns
@@ -540,8 +579,6 @@ class QueryCursor:
             boxes = eng.store.spatial_box_of(uniq_ents)
             has_geom = ~np.isnan(boxes[:, 0])
             uniq_ents, boxes = uniq_ents[has_geom], boxes[has_geom]
-        if key is not None:
-            sc[key] = (drv_rel, uniq_ents, boxes)
         return drv_rel, uniq_ents, boxes
 
     def _sip_prefetch(self, b0: int) -> None:
@@ -564,8 +601,9 @@ class QueryCursor:
 
     def _materialize_window(self, b0: int) -> list[tuple]:
         """Materialize (and cache in `pending`) a lookahead window."""
-        mats = [(w,) + self._materialize(w)
-                for w in range(b0, min(b0 + self.window, self.n_blocks))]
+        with spans.span("streak.scan", rid=self.rid):
+            mats = [(w,) + self._materialize(w)
+                    for w in range(b0, min(b0 + self.window, self.n_blocks))]
         for w, drv_rel, uniq_ents, boxes in mats:
             self.pending[w] = (drv_rel, uniq_ents, boxes)
         return mats
@@ -585,46 +623,58 @@ class QueryCursor:
         APS `key_needed` (global-θ exchange) is exact: earlier shards'
         pushes only tighten later shards' pruning, never change the union.
         """
-        eng = self.engine
-        cfg, plan = eng.config, self.plan
-        driven = self.driven
-        topk, stats = self.topk, self.stats
+        cfg, stats = self.engine.config, self.stats
         if cfg.use_sip and all(len(v) == 0 for v in v_star):
             return  # nothing on the driven side can join this block
         stats.v_star_sizes.append(sum(len(v) for v in v_star))
         for si, sh in enumerate(self.shards):
             if cfg.use_sip and len(v_star[si]) == 0:
                 continue
-            intervals, explicit = sh.filter_material(v_star[si])
-
-            # ---- APS plan decision ----------------------------------
-            # θ re-read per shard: the cross-shard pruning exchange
-            key_needed = (topk.theta
-                          - (self._driver_primary_best + self.driver_other)
-                          - eng._side_bound(driven, plan.descending, True)) \
-                if topk.full else -np.inf
-            decision = aps.choose(sh.tree, v_star[si], plan.driven_cs,
-                                  driven.scan, key_needed, drv_rel.n,
-                                  cfg.cost_params, self.card_all[si])
-            chosen = cfg.force_plan or decision.plan
-            if driven.scan is None:
-                chosen = "S"
-            stats.plan_log.append(chosen)
-            if chosen == "N":
-                stats.plan_n += 1
-                dvn_rel = eng._driven_nplan(driven, plan, intervals,
-                                            explicit, key_needed, stats)
-            else:
-                stats.plan_s += 1
-                dvn_rel = eng._driven_splan(driven, plan, intervals,
-                                            explicit, stats)
+            with spans.span("streak.scan", rid=self.rid):
+                dvn_rel = self._retrieve(sh, si, v_star[si], drv_rel.n)
             if dvn_rel.n:
                 self._phase3(drv_rel, uniq_ents, boxes, dvn_rel,
                              batcher=batcher)
 
+    def _retrieve(self, sh, si: int, v_star, n_driver: int) -> Relation:
+        """APS plan decision + driven retrieval on shard view `sh`."""
+        eng, plan, driven = self.engine, self.plan, self.driven
+        cfg, topk, stats = eng.config, self.topk, self.stats
+        intervals, explicit = sh.filter_material(v_star)
+        # θ re-read per shard: the cross-shard pruning exchange
+        key_needed = (topk.theta
+                      - (self._driver_primary_best + self.driver_other)
+                      - eng._side_bound(driven, plan.descending, True)) \
+            if topk.full else -np.inf
+        decision = aps.choose(sh.tree, v_star, plan.driven_cs,
+                              driven.scan, key_needed, n_driver,
+                              cfg.cost_params, self.card_all[si])
+        chosen = cfg.force_plan or decision.plan
+        if driven.scan is None:
+            chosen = "S"
+        stats.plan_log.append(chosen)
+        if chosen == "N":
+            stats.plan_n += 1
+            return eng._driven_nplan(driven, plan, intervals, explicit,
+                                     key_needed, stats)
+        stats.plan_s += 1
+        return eng._driven_splan(driven, plan, intervals, explicit, stats)
+
     def _phase3(self, drv_rel, uniq_ents, boxes, dvn_rel,
                 batcher=None) -> None:
         """Phase-3 spatial join + refinement of one driven relation."""
+        with spans.span("streak.phase3", rid=self.rid):
+            pairs = self._mbr_pairs(drv_rel, uniq_ents, boxes, dvn_rel,
+                                    batcher)
+        if pairs is not None:
+            self.engine._emit_pairs(*pairs, drv_rel, dvn_rel, self.driver,
+                                    self.driven, self.plan, self.topk,
+                                    self.stats, rid=self.rid)
+
+    def _mbr_pairs(self, drv_rel, uniq_ents, boxes, dvn_rel, batcher):
+        """The MBR join: (pi, pj, uniq_ents, dvn_ents) for the caller to
+        refine, or None when the fused stream refined its pairs itself (or
+        registered with `batcher` to do so) or nothing can pair."""
         eng = self.engine
         cfg, plan = eng.config, self.plan
         driver, driven = self.driver, self.driven
@@ -634,7 +684,7 @@ class QueryCursor:
         ok = ~np.isnan(dvn_boxes[:, 0])
         dvn_ents, dvn_boxes = dvn_ents[ok], dvn_boxes[ok]
         if len(dvn_ents) == 0:
-            return
+            return None
         if cfg.mbr_join_fn is None and plan.join_backend == "fused":
             # streaming fused path: driven columns arrive in score-key
             # order, each batch refined+scored+pushed before the next so
@@ -645,41 +695,38 @@ class QueryCursor:
             def emit(pi, pj):
                 eng._emit_pairs(pi, pj, uniq_ents, dvn_ents, drv_rel,
                                 dvn_rel, driver, driven, plan, topk,
-                                stats, ds=ds, vs=vs)
+                                stats, ds=ds, vs=vs, rid=self.rid)
 
             if batcher is not None:
                 batcher.add(spatial_join.StreamEntry(
                     boxes, dvn_boxes, ds, vs, plan.dist_norm, plan.k,
                     theta_fn=lambda: topk.theta, emit=emit,
                     stats=stats.join))
-                return
+                return None
             for pi, pj in spatial_join.fused_stream_join(
                     boxes, dvn_boxes, ds, vs, plan.dist_norm, k=plan.k,
                     theta_fn=lambda: topk.theta,
                     batch_cols=cfg.fused_batch_cols, stats=stats.join,
                     tuner=eng.kcap_tuner):
                 emit(pi, pj)
-        else:
+            return None
+
+        def mbr_join():
             join_fn = cfg.mbr_join_fn or spatial_join.mbr_distance_join
+            return join_fn(boxes, dvn_boxes, plan.dist_norm,
+                           plan.join_backend, stats.join)
+
+        if cfg.mbr_join_fn is not None:
+            pi, pj = mbr_join()
+        else:
             # the MBR pair set is pure in (boxes, driven boxes, distance),
-            # so same-shape tenants share it too; a cache hit skips the
-            # per-launch JoinStats counters (they count work done, and a
-            # hit does none)
-            sc = eng.share_cache
-            key = None
-            if sc is not None and cfg.mbr_join_fn is None:
-                key = ("mbr", plan.join_backend, boxes.shape,
-                       dvn_boxes.shape, boxes.tobytes(),
-                       dvn_boxes.tobytes(), float(plan.dist_norm))
-            if key is not None and key in sc:
-                pi, pj = sc[key]
-            else:
-                pi, pj = join_fn(boxes, dvn_boxes, plan.dist_norm,
-                                 plan.join_backend, stats.join)
-                if key is not None:
-                    sc[key] = (pi, pj)
-            eng._emit_pairs(pi, pj, uniq_ents, dvn_ents, drv_rel,
-                            dvn_rel, driver, driven, plan, topk, stats)
+            # so same-shape tenants share it too
+            pi, pj = eng.shared(
+                "mbr", lambda: (plan.join_backend, boxes.shape,
+                                dvn_boxes.shape, boxes.tobytes(),
+                                dvn_boxes.tobytes(), float(plan.dist_norm)),
+                mbr_join)
+        return pi, pj, uniq_ents, dvn_ents
 
     # -- serial mode ----------------------------------------------------
     def step(self) -> None:

@@ -211,6 +211,12 @@ class FaultStats:
     # successful attempts per (op, backend): which route really ran
     calls: collections.Counter = dataclasses.field(
         default_factory=collections.Counter)
+    # bytes per op that its `kernels/ops` dispatches handed to the device
+    # (host arrays uploaded) and brought back (device results fetched)
+    h2d_bytes: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
+    d2h_bytes: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
 
 
 class FaultState:
@@ -226,6 +232,7 @@ class FaultState:
         self.breaker_threshold = 3
         self.breaker_cooldown_s = 30.0
         self.stats = FaultStats()
+        self.route: str | None = None   # backend of the last op that ran
 
     def breaker(self, op: str, backend: str) -> CircuitBreaker:
         key = (op, backend)
@@ -241,6 +248,7 @@ class FaultState:
         self.watchdog_s = None
         self.breakers.clear()
         self.stats = FaultStats()
+        self.route = None
 
 
 STATE = FaultState()
@@ -328,7 +336,8 @@ def run_op(op: str, attempts: list, validate=None):
     fault-free hot path never pays for it. The first failure of each
     (op, backend) is logged as a warning, so a backend that cannot run on
     this platform (a kernel the compiler refuses, say) does not hide behind
-    its bit-identical fallback; `STATE.stats.calls` counts the successes.
+    its bit-identical fallback; `STATE.stats.calls` counts the successes
+    and `STATE.route` names the backend of the last one.
 
     Raises FallbackExhausted when no backend survives.
     """
@@ -366,6 +375,7 @@ def run_op(op: str, attempts: list, validate=None):
             if ai:
                 st.stats.fallbacks += 1
             st.stats.calls[(op, backend)] += 1
+            st.route = backend
             return out
         except Exception as e:      # noqa: BLE001 — any failure fails over
             if (op, backend) not in st.breakers:   # its first failure

@@ -24,6 +24,7 @@ import numpy as np
 
 from . import node_select, squadtree
 from .squadtree import SQuadTree, build as build_tree
+from .spans import span
 from .store import (QuadStore, _entity_cs_csr, _sorted_lut,
                     lut_get)
 
@@ -203,13 +204,15 @@ def sip_select(shards: list[TreeShard], box_sets, dist_norm,
     """Phases 1+2 across shards: candidate masks then the per-shard V*
     selection DP. Returns per-BLOCK lists of per-shard V* arrays (the
     shape `QueryCursor._vstars` stores)."""
-    masks = candidate_nodes_sharded(
-        shards, box_sets, dist_norm, driven_cs, prepared=prepared,
-        probe_backend=probe_backend, descend_backend=descend_backend,
-        cs_paths=cs_paths)
-    per_shard = [node_select.select_batch(sh.tree, masks[si], driven_cs,
-                                          params, card_all[si])
-                 for si, sh in enumerate(shards)]
+    with span("streak.phase1", rows=len(box_sets)):
+        masks = candidate_nodes_sharded(
+            shards, box_sets, dist_norm, driven_cs, prepared=prepared,
+            probe_backend=probe_backend, descend_backend=descend_backend,
+            cs_paths=cs_paths)
+    with span("streak.phase2", rows=len(box_sets)):
+        per_shard = [node_select.select_batch(sh.tree, masks[si], driven_cs,
+                                              params, card_all[si])
+                     for si, sh in enumerate(shards)]
     n_blocks = len(masks[0]) if len(shards) else 0
     return [[per_shard[si][b] for si in range(len(shards))]
             for b in range(n_blocks)]

@@ -211,11 +211,9 @@ def fused_stream_join(driver_boxes: np.ndarray, driven_boxes: np.ndarray,
         theta32 = _theta32_lower(theta)
         chunk = dvn_sorted[start:start + batch_cols]
         ck = vs_sorted[start:start + batch_cols]
-        scores, idx, counts = kops.fused_topk_join(
+        _, idx, counts = kops.fused_topk_join(
             drv, chunk, ds, ck, float(dist_norm), theta32, k=kcap,
-            interpret=interpret)
-        idx = np.asarray(idx)
-        counts = np.asarray(counts)
+            interpret=interpret, fetch_scores=False)
         if tuner is not None:
             tuner.update(counts)
         if stats is not None:
@@ -374,12 +372,12 @@ def fused_stream_join_multi(entries: list[StreamEntry],
             ck_l.append(np.full(n_pad, -np.inf, np.float32))
             cq_l.append(np.full(n_pad, -2, np.int32))
         try:
-            scores, idx, counts = kops.fused_topk_join(
+            _, idx, counts = kops.fused_topk_join(
                 np.concatenate(drv_l), np.concatenate(col_l),
                 np.concatenate(ds_l), np.concatenate(ck_l),
                 np.concatenate(dist_l), np.concatenate(th_l), k=kcap,
                 row_qid=np.concatenate(rq_l), col_qid=np.concatenate(cq_l),
-                interpret=interpret)
+                interpret=interpret, fetch_scores=False)
         except Exception as exc:    # noqa: BLE001 — whole-launch failure
             # the shared launch died past the failover chain: every rider
             # faults (their owners restart from fresh cursors); entries not
@@ -387,8 +385,6 @@ def fused_stream_join_multi(entries: list[StreamEntry],
             for c, *_ in spans:
                 c.e.error = exc
             continue
-        idx = np.asarray(idx)
-        counts = np.asarray(counts)
         launches += 1
         if tuner is not None:
             tuner.update(counts)
@@ -461,8 +457,8 @@ def fused_topk_pairs(driver_boxes: np.ndarray, driven_boxes: np.ndarray,
             np.ascontiguousarray(driver_boxes, dtype=np.float32), chunk,
             ds, vs[start:start + batch_cols], float(dist_norm), theta32,
             k=kcap, interpret=interpret)
-        idx = np.asarray(idx).astype(np.int64)
-        parts.append((np.asarray(scores),
+        idx = idx.astype(np.int64)
+        parts.append((scores,
                       np.where(idx >= 0, idx + start, -1)))
     if not parts:
         return (np.full((m, kcap), -np.inf, np.float32),

@@ -12,6 +12,12 @@ breakers remember repeated failures; `BackendPolicy.resolve` consults them
 so later plans skip a broken backend at plan time. The chains cost one
 function call and a dict probe per *dispatch* (per driver block, not per
 row); the structural validators only run when a `FaultPlan` is installed.
+
+Each of those dispatchers is one `_Dispatch`: a ``streak.kernel`` span
+from entry to its host result (padding, key split, upload, launch, fetch),
+tagged with the op, the route `run_op` took and the shapes handed to the
+device, and the bytes it uploads and fetches, counted per op on
+`fault.STATE.stats` (``h2d_bytes``, ``d2h_bytes``).
 """
 from __future__ import annotations
 
@@ -22,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import fault as _fault
+from ..core import spans as _spans
 from . import block_scan as _bs
 from . import bloom_probe as _bp
 from . import distance_join as _dj
@@ -38,32 +45,81 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+class _Dispatch:
+    """One public dispatch: its ``streak.kernel`` span and the bytes it
+    moves between host and device (see the module docstring). The route
+    and the shapes are noted only while a profiler records."""
+
+    __slots__ = ("op", "h2d", "d2h", "shapes", "span")
+
+    def __init__(self, op: str):
+        self.op = op
+        self.h2d = self.d2h = 0
+        self.shapes: list[str] | None = None
+
+    def __enter__(self) -> "_Dispatch":
+        self.span = _spans.span("streak.kernel", op=self.op)
+        self.span.__enter__()
+        if self.span.is_enabled():
+            self.shapes = []
+        return self
+
+    def __exit__(self, *exc):
+        if self.h2d or self.d2h:
+            st = _fault.STATE.stats
+            st.h2d_bytes[self.op] += self.h2d
+            st.d2h_bytes[self.op] += self.d2h
+        if self.shapes is not None:
+            route = _fault.STATE.route if exc[0] is None else None
+            self.span.set_metadata(backend=str(route),
+                                   shapes=" ".join(self.shapes))
+        return self.span.__exit__(*exc)
+
+    def up(self, x, dtype=None):
+        """`x` as a device array; a host array's bytes count as uploaded."""
+        on_host = not isinstance(x, jax.Array)
+        d = jnp.asarray(x, dtype=dtype)
+        if on_host:
+            self.h2d += d.nbytes
+            if self.shapes is not None:
+                self.shapes.append(f"{d.dtype.name}{list(d.shape)}")
+        return d
+
+    def down(self, x) -> np.ndarray:
+        """`x` as a host array; a device array's bytes count as fetched."""
+        if isinstance(x, jax.Array):
+            self.d2h += x.nbytes
+        return np.asarray(x)
+
+
 def _v_dist_matrix(out) -> bool:
     a = np.asarray(out)
     return bool(np.isfinite(a).all() and (a >= 0).all())
 
 
 def distance_join_matrix(driver, driven, interpret: bool | None = None):
-    driver = jnp.asarray(driver, dtype=jnp.float32)
-    driven = jnp.asarray(driven, dtype=jnp.float32)
+    with _Dispatch("distance_join_matrix") as call:
+        driver = call.up(driver, jnp.float32)
+        driven = call.up(driven, jnp.float32)
 
-    def oracle():
-        return ref.distance_join_ref(driver, driven)
+        def oracle():
+            return ref.distance_join_ref(driver, driven)
 
-    if _on_tpu() or interpret:
-        live = "interpret" if (interpret and not _on_tpu()) else "kernel"
-        attempts = [
-            (live, lambda: _dj.distance_join(
-                driver, driven, interpret=bool(interpret) and not _on_tpu())),
-            ("oracle", oracle),
-        ]
-    else:
-        # numpy-free CPU route: the jnp oracle is already the live backend;
-        # the trailing attempt retries the same pure function (recovers
-        # injected/transient failures, not deterministic ones)
-        attempts = [("jit", oracle), ("oracle", oracle)]
-    return _fault.run_op("distance_join_matrix", attempts,
-                         validate=_v_dist_matrix)
+        if _on_tpu() or interpret:
+            live = "interpret" if (interpret and not _on_tpu()) else "kernel"
+            attempts = [
+                (live, lambda: _dj.distance_join(
+                    driver, driven,
+                    interpret=bool(interpret) and not _on_tpu())),
+                ("oracle", oracle),
+            ]
+        else:
+            # numpy-free CPU route: the jnp oracle is already the live
+            # backend; the trailing attempt retries the same pure function
+            # (recovers injected/transient failures, not deterministic ones)
+            attempts = [("jit", oracle), ("oracle", oracle)]
+        return call.down(_fault.run_op("distance_join_matrix", attempts,
+                                       validate=_v_dist_matrix))
 
 
 def distance_join_mask(driver, driven, dist: float,
@@ -74,47 +130,55 @@ def distance_join_mask(driver, driven, dist: float,
 def fused_topk_join(driver, driven, driver_keys, driven_keys,
                     dist, theta, k: int = 64,
                     row_qid=None, col_qid=None,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None,
+                    fetch_scores: bool = True):
     """Streaming per-row top-k distance join; see kernels/fused_topk_join.py.
 
     `dist` / `theta` may be scalars or per-driver-row (M,) arrays; `row_qid`
     / `col_qid` optional int32 query ids mask cross-query pairs so several
     queries' blocks share one launch (serve/spatial.py). Returns
     (scores (M, k), idx (M, k), counts (M,)) — the per-row partials the
-    `fused` join backend consumes. On CPU without interpret mode this runs
+    `fused` join backend consumes — as host arrays; with `fetch_scores`
+    false the scores stay on the device and None stands in their place.
+    On CPU without interpret mode this runs
     the dense jnp oracle (still per column *batch* when called through
     core/spatial_join.py, so peak memory stays independent of total N).
     """
-    driver = jnp.asarray(driver, dtype=jnp.float32)
-    driven = jnp.asarray(driven, dtype=jnp.float32)
-    dk = jnp.asarray(driver_keys, dtype=jnp.float32)
-    vk = jnp.asarray(driven_keys, dtype=jnp.float32)
-    m, n = driver.shape[0], driven.shape[0]
-    # one jit signature for scalar and per-row callers: always materialize
-    # the per-row threshold columns and the qid planes
-    dist_arr = jnp.broadcast_to(jnp.asarray(dist, dtype=jnp.float32), (m,))
-    theta_arr = jnp.broadcast_to(jnp.asarray(theta, dtype=jnp.float32), (m,))
-    rq = (jnp.zeros(m, jnp.int32) if row_qid is None
-          else jnp.asarray(row_qid, dtype=jnp.int32))
-    cq = (jnp.zeros(n, jnp.int32) if col_qid is None
-          else jnp.asarray(col_qid, dtype=jnp.int32))
-    def oracle():
-        return _fused_ref_jit(driver, driven, dk, vk, dist_arr, theta_arr,
-                              rq, cq, k)
+    with _Dispatch("fused_topk_join") as call:
+        driver = call.up(driver, jnp.float32)
+        driven = call.up(driven, jnp.float32)
+        dk = call.up(driver_keys, jnp.float32)
+        vk = call.up(driven_keys, jnp.float32)
+        m, n = driver.shape[0], driven.shape[0]
+        # one jit signature for scalar and per-row callers: always
+        # materialize the per-row threshold columns and the qid planes
+        dist_arr = jnp.broadcast_to(call.up(dist, jnp.float32), (m,))
+        theta_arr = jnp.broadcast_to(call.up(theta, jnp.float32), (m,))
+        rq = (jnp.zeros(m, jnp.int32) if row_qid is None
+              else call.up(row_qid, jnp.int32))
+        cq = (jnp.zeros(n, jnp.int32) if col_qid is None
+              else call.up(col_qid, jnp.int32))
 
-    if _on_tpu() or interpret:
-        live = "interpret" if (interpret and not _on_tpu()) else "kernel"
-        attempts = [
-            (live, lambda: _ftj.fused_topk_join(
-                driver, driven, dk, vk, dist_arr, theta_arr, k=k,
-                row_qid=rq, col_qid=cq,
-                interpret=bool(interpret) and not _on_tpu())),
-            ("oracle", oracle),
-        ]
-    else:
-        attempts = [("jit", oracle), ("oracle", oracle)]
-    return _fault.run_op("fused_topk_join", attempts,
-                         validate=functools.partial(_v_fused, n=n))
+        def oracle():
+            return _fused_ref_jit(driver, driven, dk, vk, dist_arr,
+                                  theta_arr, rq, cq, k)
+
+        if _on_tpu() or interpret:
+            live = "interpret" if (interpret and not _on_tpu()) else "kernel"
+            attempts = [
+                (live, lambda: _ftj.fused_topk_join(
+                    driver, driven, dk, vk, dist_arr, theta_arr, k=k,
+                    row_qid=rq, col_qid=cq,
+                    interpret=bool(interpret) and not _on_tpu())),
+                ("oracle", oracle),
+            ]
+        else:
+            attempts = [("jit", oracle), ("oracle", oracle)]
+        scores, idx, counts = _fault.run_op(
+            "fused_topk_join", attempts,
+            validate=functools.partial(_v_fused, n=n))
+        return (call.down(scores) if fetch_scores else None,
+                call.down(idx), call.down(counts))
 
 
 def _v_fused(out, n: int) -> bool:
@@ -140,25 +204,27 @@ def bucketed_min_core(a_planes, b_planes, interpret: bool | None = None):
     unit-sphere X/Y/Z for haversine). Returns (B,) float32 core minima —
     the caller applies the metric's monotone distance transform in float64
     (core/spatial_join.py::core_to_dist)."""
-    a_planes = tuple(jnp.asarray(p, dtype=jnp.float32) for p in a_planes)
-    b_planes = tuple(jnp.asarray(p, dtype=jnp.float32) for p in b_planes)
+    with _Dispatch("bucketed_min_core") as call:
+        a_planes = tuple(call.up(p, jnp.float32) for p in a_planes)
+        b_planes = tuple(call.up(p, jnp.float32) for p in b_planes)
 
-    def host():
-        # CPU: the loop-structured host twin (kernel numerics, no (B, m, n)
-        # cube); ref.bucketed_min_core_ref stays the test oracle
-        return _gr.bucketed_min_core_host(a_planes, b_planes)
+        def host():
+            # CPU: the loop-structured host twin (kernel numerics, no
+            # (B, m, n) cube); ref.bucketed_min_core_ref stays the oracle
+            return _gr.bucketed_min_core_host(a_planes, b_planes)
 
-    if _on_tpu() or interpret:
-        live = "interpret" if (interpret and not _on_tpu()) else "kernel"
-        attempts = [
-            (live, lambda: _gr.bucketed_min_core(
-                a_planes, b_planes,
-                interpret=bool(interpret) and not _on_tpu())),
-            ("oracle", host),
-        ]
-    else:
-        attempts = [("jit", host), ("oracle", host)]
-    return _fault.run_op("bucketed_min_core", attempts, validate=_v_min_core)
+        if _on_tpu() or interpret:
+            live = "interpret" if (interpret and not _on_tpu()) else "kernel"
+            attempts = [
+                (live, lambda: _gr.bucketed_min_core(
+                    a_planes, b_planes,
+                    interpret=bool(interpret) and not _on_tpu())),
+                ("oracle", host),
+            ]
+        else:
+            attempts = [("jit", host), ("oracle", host)]
+        return call.down(_fault.run_op("bucketed_min_core", attempts,
+                                       validate=_v_min_core))
 
 
 def _v_min_core(out) -> bool:
@@ -225,38 +291,39 @@ def merge_join_ranks(table, probes, backend: str | None = None,
         return (np.searchsorted(table, probes, "left"),
                 np.searchsorted(table, probes, "right"))
 
-    if backend == "numpy":
-        attempts = [("numpy", numpy_ranks), ("oracle", numpy_ranks)]
-    else:
-        def accel(backend=backend):
-            # pow2 size classes bound jit recompiles; the int64-max sentinel
-            # compares greater than every probe, so table padding never
-            # changes a rank, and padded probe rows are sliced off below
-            t_hi, t_lo = split_key_planes(_pad_pow2(table, (1 << 63) - 1))
-            p_hi, p_lo = split_key_planes(_pad_pow2(probes, 0))
-            if backend == "cpu":
-                out = _mj.merge_join_ranks_host(t_hi, t_lo, p_hi, p_lo,
-                                                side=side)
-                if side != "both":
-                    return np.asarray(out[:m]).astype(np.int64)
-                lo, hi = out
-            elif backend == "kernel" and not _on_tpu():
-                lo, hi = _ranks_ref_jit(jnp.asarray(t_hi), jnp.asarray(t_lo),
-                                        jnp.asarray(p_hi), jnp.asarray(p_lo))
-            else:
-                lo, hi = _mj.merge_join_ranks(
-                    jnp.asarray(t_hi), jnp.asarray(t_lo),
-                    jnp.asarray(p_hi), jnp.asarray(p_lo),
-                    interpret=backend == "interpret" and not _on_tpu())
-            lo = np.asarray(lo[:m]).astype(np.int64)
-            hi = np.asarray(hi[:m]).astype(np.int64)
-            return ((lo, hi) if side == "both"
-                    else (lo if side == "left" else hi))
+    with _Dispatch("merge_join_ranks") as call:
+        if backend == "numpy":
+            attempts = [("numpy", numpy_ranks), ("oracle", numpy_ranks)]
+        else:
+            def accel(backend=backend):
+                # pow2 size classes bound jit recompiles; the int64-max
+                # sentinel compares greater than every probe, so table
+                # padding never changes a rank, and padded probe rows are
+                # sliced off below
+                t_hi, t_lo = split_key_planes(
+                    _pad_pow2(table, (1 << 63) - 1))
+                p_hi, p_lo = split_key_planes(_pad_pow2(probes, 0))
+                planes = [call.up(a) for a in (t_hi, t_lo, p_hi, p_lo)]
+                if backend == "cpu":
+                    out = _mj.merge_join_ranks_host(*planes, side=side)
+                    if side != "both":
+                        return call.down(out[:m]).astype(np.int64)
+                    lo, hi = out
+                elif backend == "kernel" and not _on_tpu():
+                    lo, hi = _ranks_ref_jit(*planes)
+                else:
+                    lo, hi = _mj.merge_join_ranks(
+                        *planes,
+                        interpret=backend == "interpret" and not _on_tpu())
+                lo = call.down(lo[:m]).astype(np.int64)
+                hi = call.down(hi[:m]).astype(np.int64)
+                return ((lo, hi) if side == "both"
+                        else (lo if side == "left" else hi))
 
-        attempts = [(backend, accel), ("oracle", numpy_ranks)]
-    return _fault.run_op(
-        "merge_join_ranks", attempts,
-        validate=functools.partial(_v_ranks, n=len(table), side=side))
+            attempts = [(backend, accel), ("oracle", numpy_ranks)]
+        return _fault.run_op(
+            "merge_join_ranks", attempts,
+            validate=functools.partial(_v_ranks, n=len(table), side=side))
 
 
 def _v_ranks(out, n: int, side: str) -> bool:
@@ -321,36 +388,37 @@ def tree_descend(node_keys, cs_path, box_keys, backend: str = "kernel",
     b, m = box_keys.shape[0], box_keys.shape[1]
     if n == 0 or b == 0:
         return np.zeros((b, n), dtype=bool)
-    # pow2 size classes bound jit recompiles: padded blocks/boxes carry the
-    # never-intersecting sentinel box and are sliced off / ignored below
-    bp = 1 << max(int(b - 1).bit_length(), 0)
-    mp = 1 << max(int(m - 1).bit_length(), 3)
-    if bp != b or mp != m:
-        padded = np.empty((bp, mp, 4), dtype=np.int64)
-        padded[:] = DESCEND_PAD_BOX
-        padded[:b, :m] = box_keys
-        box_keys = padded
-    n_hi, n_lo = split_key_planes(node_keys)
-    b_hi, b_lo = split_key_planes(box_keys)
-    cs = np.asarray(cs_path).astype(np.int32)
+    with _Dispatch("tree_descend") as call:
+        # pow2 size classes bound jit recompiles: padded blocks/boxes carry
+        # the never-intersecting sentinel box and are sliced off / ignored
+        bp = 1 << max(int(b - 1).bit_length(), 0)
+        mp = 1 << max(int(m - 1).bit_length(), 3)
+        if bp != b or mp != m:
+            padded = np.empty((bp, mp, 4), dtype=np.int64)
+            padded[:] = DESCEND_PAD_BOX
+            padded[:b, :m] = box_keys
+            box_keys = padded
+        n_hi, n_lo = split_key_planes(node_keys)
+        b_hi, b_lo = split_key_planes(box_keys)
+        cs = np.asarray(cs_path).astype(np.int32)
 
-    def oracle():
-        return _descend_ref_jit(jnp.asarray(n_hi), jnp.asarray(n_lo),
-                                jnp.asarray(cs), jnp.asarray(b_hi),
-                                jnp.asarray(b_lo))
+        def planes():
+            return [call.up(a) for a in (n_hi, n_lo, cs, b_hi, b_lo)]
 
-    if backend == "kernel" and not _on_tpu():
-        attempts = [("kernel", oracle), ("oracle", oracle)]
-    else:
-        attempts = [
-            (backend, lambda: _td.tree_descend(
-                jnp.asarray(n_hi), jnp.asarray(n_lo), jnp.asarray(cs),
-                jnp.asarray(b_hi), jnp.asarray(b_lo),
-                interpret=backend == "interpret" and not _on_tpu())),
-            ("oracle", oracle),
-        ]
-    out = _fault.run_op("tree_descend", attempts, validate=_v_mask01)
-    return np.asarray(out[:b]) != 0
+        def oracle():
+            return _descend_ref_jit(*planes())
+
+        if backend == "kernel" and not _on_tpu():
+            attempts = [("kernel", oracle), ("oracle", oracle)]
+        else:
+            attempts = [
+                (backend, lambda: _td.tree_descend(
+                    *planes(),
+                    interpret=backend == "interpret" and not _on_tpu())),
+                ("oracle", oracle),
+            ]
+        out = _fault.run_op("tree_descend", attempts, validate=_v_mask01)
+        return call.down(out[:b]) != 0
 
 
 def tree_descend_sharded(node_keys, cs_path, box_keys,
@@ -378,35 +446,37 @@ def tree_descend_sharded(node_keys, cs_path, box_keys,
     b, m = box_keys.shape[0], box_keys.shape[1]
     if s == 0 or n == 0 or b == 0:
         return np.zeros((s, b, n), dtype=bool)
-    bp = 1 << max(int(b - 1).bit_length(), 0)
-    mp = 1 << max(int(m - 1).bit_length(), 3)
-    padded = box_keys
-    if bp != b or mp != m:
-        padded = np.empty((bp, mp, 4), dtype=np.int64)
-        padded[:] = DESCEND_PAD_BOX
-        padded[:b, :m] = box_keys
-    cs = np.asarray(cs_path).astype(np.int32)
+    with _Dispatch("tree_descend_sharded") as call:
+        bp = 1 << max(int(b - 1).bit_length(), 0)
+        mp = 1 << max(int(m - 1).bit_length(), 3)
+        padded = box_keys
+        if bp != b or mp != m:
+            padded = np.empty((bp, mp, 4), dtype=np.int64)
+            padded[:] = DESCEND_PAD_BOX
+            padded[:b, :m] = box_keys
+        cs = np.asarray(cs_path).astype(np.int32)
 
-    def via_shard_map():
-        from ..launch import mesh as _mesh
-        n_hi, n_lo = split_key_planes(node_keys)
-        b_hi, b_lo = split_key_planes(padded)
-        f = sharded_descend_fn(_mesh.make_shard_mesh(s),
-                               pallas=backend == "interpret" or _on_tpu(),
-                               interpret=backend == "interpret"
-                               and not _on_tpu())
-        out = f(jnp.asarray(n_hi), jnp.asarray(n_lo), jnp.asarray(cs),
-                jnp.asarray(b_hi), jnp.asarray(b_lo))
-        return np.asarray(out)[:, :b]
+        def via_shard_map():
+            from ..launch import mesh as _mesh
+            n_hi, n_lo = split_key_planes(node_keys)
+            b_hi, b_lo = split_key_planes(padded)
+            f = sharded_descend_fn(_mesh.make_shard_mesh(s),
+                                   pallas=backend == "interpret" or _on_tpu(),
+                                   interpret=backend == "interpret"
+                                   and not _on_tpu())
+            out = f(*[call.up(a) for a in (n_hi, n_lo, cs, b_hi, b_lo)])
+            return call.down(out)[:, :b]
 
-    def sequential():
-        return np.stack([
-            tree_descend(node_keys[i], cs[i], box_keys, backend=backend)
-            .astype(np.int32) for i in range(s)])
+        def sequential():
+            # each shard's descent is a dispatch of its own, counted there
+            return np.stack([
+                tree_descend(node_keys[i], cs[i], box_keys, backend=backend)
+                .astype(np.int32) for i in range(s)])
 
-    attempts = [("shard_map", via_shard_map), ("sequential", sequential)]
-    out = _fault.run_op("tree_descend_sharded", attempts, validate=_v_mask01)
-    return np.asarray(out) != 0
+        attempts = [("shard_map", via_shard_map), ("sequential", sequential)]
+        out = _fault.run_op("tree_descend_sharded", attempts,
+                            validate=_v_mask01)
+        return out != 0
 
 
 @functools.lru_cache(maxsize=None)
@@ -446,29 +516,32 @@ def _descend_ref_jit(n_hi, n_lo, cs, b_hi, b_lo):
 
 def bloom_probe(bits, keys, k: int = 3, interpret: bool | None = None):
     """bits (B, W) uint32 pre-gathered filter rows; keys (B,) int64."""
-    keys = np.asarray(keys, dtype=np.int64).view(np.uint64)
-    lo = jnp.asarray((keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    with _Dispatch("bloom_probe") as call:
+        keys = np.asarray(keys, dtype=np.int64).view(np.uint64)
+        lo = call.up((keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
                      .view(np.int32))
-    hi = jnp.asarray((keys >> np.uint64(32)).astype(np.uint32).view(np.int32))
-    bits = jnp.asarray(bits)
+        hi = call.up((keys >> np.uint64(32)).astype(np.uint32)
+                     .view(np.int32))
+        bits = call.up(bits)
 
-    def oracle():
-        # int verdict plane (not bool) so corrupt-injection has an
-        # out-of-domain value for the validator to catch
-        return jnp.asarray(ref.bloom_probe_ref(bits, lo, hi, k), jnp.int32)
+        def oracle():
+            # int verdict plane (not bool) so corrupt-injection has an
+            # out-of-domain value for the validator to catch
+            return jnp.asarray(ref.bloom_probe_ref(bits, lo, hi, k),
+                               jnp.int32)
 
-    if _on_tpu() or interpret:
-        live = "interpret" if (interpret and not _on_tpu()) else "kernel"
-        attempts = [
-            (live, lambda: _bp.bloom_probe(
-                bits, lo, hi, k=k,
-                interpret=bool(interpret) and not _on_tpu())),
-            ("oracle", oracle),
-        ]
-    else:
-        attempts = [("jit", oracle), ("oracle", oracle)]
-    out = _fault.run_op("bloom_probe", attempts, validate=_v_mask01)
-    return np.asarray(out) == 1
+        if _on_tpu() or interpret:
+            live = "interpret" if (interpret and not _on_tpu()) else "kernel"
+            attempts = [
+                (live, lambda: _bp.bloom_probe(
+                    bits, lo, hi, k=k,
+                    interpret=bool(interpret) and not _on_tpu())),
+                ("oracle", oracle),
+            ]
+        else:
+            attempts = [("jit", oracle), ("oracle", oracle)]
+        out = _fault.run_op("bloom_probe", attempts, validate=_v_mask01)
+        return call.down(out) == 1
 
 
 def block_scan(scores, theta: float, interpret: bool | None = None):

@@ -41,9 +41,10 @@ import dataclasses
 import numpy as np
 
 from ..core import fault, node_select, shard as shard_mod, spatial_join
-from ..core.executor import ExecStats, QueryCursor, StreakEngine
+from ..core.executor import ExecStats, QueryCursor, ShareCache, StreakEngine
 from ..core.join import Relation
 from ..core.query import Query
+from ..core.spans import span
 
 
 @dataclasses.dataclass
@@ -96,8 +97,9 @@ class _FusedJoinBatcher:
     def flush(self) -> int:
         if not self.entries:
             return 0
-        launches = spatial_join.fused_stream_join_multi(
-            self.entries, batch_cols=self.batch_cols, tuner=self.tuner)
+        with span("streak.phase3", slots=len(self.entries)):
+            launches = spatial_join.fused_stream_join_multi(
+                self.entries, batch_cols=self.batch_cols, tuner=self.tuner)
         self.entries = []
         return launches
 
@@ -118,10 +120,9 @@ class SpatialServeEngine:
         # (executor.StreakEngine.share_cache) and pooled Phase-1/2 rows
         # (deduped in step()). Serial per-query execution recomputes all
         # of it per tenant.
-        self.engine.share_cache = {}
+        self.engine.share_cache = ShareCache(share_cache_max)
         self.max_slots = max_slots
         self.max_retries = max_retries
-        self.share_cache_max = share_cache_max
         self.slots: list[tuple[SpatialRequest, QueryCursor] | None] = \
             [None] * max_slots
         self.queue: list[SpatialRequest] = []
@@ -172,8 +173,10 @@ class SpatialServeEngine:
                     continue
                 self.queue.pop(i)
                 try:
-                    cur = self.engine.cursor(req.query,
-                                             deadline=req.deadline)
+                    with span("streak.admit", rid=req.rid):
+                        cur = self.engine.cursor(req.query,
+                                                 deadline=req.deadline,
+                                                 rid=req.rid)
                 except Exception as exc:    # noqa: BLE001 — surface per-req
                     self.stats.admission_failures += 1
                     self._fail(req, exc)
@@ -196,10 +199,11 @@ class SpatialServeEngine:
         self.slots[slot] = None
 
     # ------------------------------------------------------------------
-    def _slot_sip(self, r: dict) -> list:
+    def _slot_sip(self, r: dict, rid) -> list:
         """Per-slot serial Phase-1/2 (the pooled call's degraded mode): the
         same per-shard candidate_nodes + select_batch, one tenant's rows
-        only. Returns per-row lists of per-shard V* arrays."""
+        only (request `rid`). Returns per-row lists of per-shard V*
+        arrays."""
         shards = shard_mod.shard_views(self.engine.store)
         policy = self.engine.config.policy
         boxes = [b if b is not None else np.zeros((0, 4))
@@ -208,15 +212,19 @@ class SpatialServeEngine:
         cs_path = r.get("cs_path")
         sel_shards = []
         for si, sh in enumerate(shards):
-            in_v = sh.tree.candidate_nodes(
-                boxes, np.full(n, r["dist_norm"]), [r["driven_cs"]] * n,
-                prepared=[r["prepared"]] * n,
-                probe_backend=policy.probe, descend_backend=policy.descend,
-                cs_path=[cs_path[si] if cs_path is not None else None] * n)
-            sel_shards.append(node_select.select_batch(
-                sh.tree, in_v, [r["driven_cs"]] * n,
-                self.engine.config.select_params,
-                card_all=np.stack([r["card_all"][si]] * n)))
+            with span("streak.phase1", rid=rid):
+                in_v = sh.tree.candidate_nodes(
+                    boxes, np.full(n, r["dist_norm"]), [r["driven_cs"]] * n,
+                    prepared=[r["prepared"]] * n,
+                    probe_backend=policy.probe,
+                    descend_backend=policy.descend,
+                    cs_path=[cs_path[si] if cs_path is not None else None]
+                    * n)
+            with span("streak.phase2", rid=rid):
+                sel_shards.append(node_select.select_batch(
+                    sh.tree, in_v, [r["driven_cs"]] * n,
+                    self.engine.config.select_params,
+                    card_all=np.stack([r["card_all"][si]] * n)))
         self.stats.sip_batches += 1
         self.stats.sip_blocks += n
         return [[sel_shards[si][i] for si in range(len(shards))]
@@ -229,7 +237,27 @@ class SpatialServeEngine:
 
         Every per-slot phase is crash-isolated: an exception advances only
         that slot to `_fault_slot` (restart or retire) while the rest of the
-        step proceeds."""
+        step proceeds. While a profiler records, the step's
+        ``streak.step`` span carries what it added to `counters()`."""
+        with span("streak.step") as sp:
+            if not sp.is_enabled():
+                return self._step()
+            before = self.counters()
+            n = self._step()
+            sp.set_metadata(**{k: v - before[k]
+                               for k, v in self.counters().items()})
+            return n
+
+    def counters(self) -> dict:
+        """Share-cache lookups and hits (every kind together) and the
+        bytes the kernel dispatches uploaded and fetched, so far."""
+        sc, fs = self.engine.share_cache, fault.STATE.stats
+        return {"share_lookups": sum(sc.lookups.values()),
+                "share_hits": sum(sc.hits.values()),
+                "h2d_bytes": sum(fs.h2d_bytes.values()),
+                "d2h_bytes": sum(fs.d2h_bytes.values())}
+
+    def _step(self) -> int:
         self._tick += 1
         self._admit()
         self.stats.max_queue = max(self.stats.max_queue, len(self.queue))
@@ -297,18 +325,21 @@ class SpatialServeEngine:
                 # cards[i] / cs_paths[i] are per-shard lists (tenant
                 # cursors expose one entry per shard view, same order)
                 sel_shards = []
+                pooled = {"rows": len(boxes), "slots": len(sip_slots)}
                 for si, sh in enumerate(shards):
-                    in_v = sh.tree.candidate_nodes(
-                        boxes, np.array(dists), cs_sets,
-                        prepared=prepared,
-                        probe_backend=policy.probe,
-                        descend_backend=policy.descend,
-                        cs_path=[p[si] if p is not None else None
-                                 for p in cs_paths])
-                    sel_shards.append(node_select.select_batch(
-                        sh.tree, in_v, cs_sets,
-                        self.engine.config.select_params,
-                        card_all=np.stack([c[si] for c in cards])))
+                    with span("streak.phase1", **pooled):
+                        in_v = sh.tree.candidate_nodes(
+                            boxes, np.array(dists), cs_sets,
+                            prepared=prepared,
+                            probe_backend=policy.probe,
+                            descend_backend=policy.descend,
+                            cs_path=[p[si] if p is not None else None
+                                     for p in cs_paths])
+                    with span("streak.phase2", **pooled):
+                        sel_shards.append(node_select.select_batch(
+                            sh.tree, in_v, cs_sets,
+                            self.engine.config.select_params,
+                            card_all=np.stack([c[si] for c in cards])))
                 for s, rows in spans:
                     v_stars[s] = [[sel_shards[si][i]
                                    for si in range(len(shards))]
@@ -323,7 +354,7 @@ class SpatialServeEngine:
                 self.stats.pooled_fallbacks += 1
                 for s, r in sip_slots:
                     try:
-                        v_stars[s] = self._slot_sip(r)
+                        v_stars[s] = self._slot_sip(r, self.slots[s][0].rid)
                     except Exception as exc:    # noqa: BLE001
                         self._fault_slot(s, exc)
 
@@ -357,22 +388,16 @@ class SpatialServeEngine:
                     if e.error is None:
                         e.error = exc
             # faulted entries (StreamEntry.error) fault only their riders
-            for s, span in entry_spans.items():
-                errs = [e.error for e in entries[span] if e.error is not None]
+            for s, part in entry_spans.items():
+                errs = [e.error for e in entries[part] if e.error is not None]
                 if errs and self.slots[s] is not None:
                     self._fault_slot(s, errs[0])
         for s, _ in work:
             if self.slots[s] is not None and self.slots[s][1].done:
                 self._retire(s)
-        # bound the cross-tenant memo (entries hold relations) with
-        # insertion-order eviction: dicts iterate oldest-first, so popping
-        # from the front drops the stalest per-block results while this
-        # step's hot entries survive
-        sc = self.engine.share_cache
-        if sc is not None:
-            while len(sc) > self.share_cache_max:
-                sc.pop(next(iter(sc)))
-                self.stats.share_evictions += 1
+        # bound the cross-tenant memo (entries hold relations)
+        self.engine.share_cache.trim()
+        self.stats.share_evictions = self.engine.share_cache.evictions
         return len(active)
 
     def run(self) -> None:

@@ -1,0 +1,13 @@
+"""Self time of the program's ``streak.phase1`` spans per engine step in
+the traced window, ms: Phase 1: candidate nodes, the tree descent and
+Bloom probes."""
+from pathlib import Path
+
+from streakbench import spans
+
+ROOT = Path(__file__).resolve().parents[2]
+
+
+def read(rec):
+    sp = spans.of_run(rec, ROOT)
+    return sp.per_step_ms("streak.phase1") if sp is not None else None
