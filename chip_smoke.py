@@ -12,7 +12,8 @@ serves its two top-k queries at eight per-tenant k each through
 probe and rank), and checks every result against the same queries run with
 the all-numpy policy. It checks the exact-refinement kernel's minima
 against numpy, and runs the four Geographica shapes (range, within, kNN,
-join) on the Geographica suite's large lgd dataset against `FullScanEngine`.
+join) on the Geographica suite's large lgd dataset against `FullScanEngine`,
+their Phase-3 joins on the device route.
 
 Four chips: shards the same store over a 4-device `make_shard_mesh`, runs
 the queries through the sharded descent (`shard_map`), and compares them
@@ -52,9 +53,13 @@ KERNELS = BackendPolicy(join="fused", descend="kernel", probe="kernel",
                         rank="kernel")
 NUMPY = BackendPolicy(join="numpy", descend="numpy", probe="numpy",
                       rank="numpy")
-# ops whose Pallas kernel must have run on the one-chip path
+# the shapes' Phase-3 joins take the device route, the TPU's default
+SHAPES = dataclasses.replace(KERNELS, join="kernel")
+# ops whose Pallas kernel must have run, and on one chip the shapes'
+# device join besides
 KERNEL_OPS = ("fused_topk_join", "tree_descend", "bloom_probe",
               "merge_join_ranks", "bucketed_min_core")
+ONE_CHIP_OPS = KERNEL_OPS + ("mbr_candidates",)
 
 
 class SmokeFailure(RuntimeError):
@@ -163,7 +168,7 @@ def refine_phase(store, seed: int, n_pairs: int = 2048,
 
 def shapes_phase(ds) -> dict:
     """The four Geographica shapes against the brute-force reference."""
-    eng = StreakEngine(ds.store, ExecConfig(policy=KERNELS))
+    eng = StreakEngine(ds.store, ExecConfig(policy=SHAPES))
     oracle = FullScanEngine(ds.store)
     out = {}
     for shape, q in bench_geo.queries(ds.ns):
@@ -291,7 +296,7 @@ def main(argv=None) -> int:
              f"built in {time.perf_counter() - t0:.3f} s")
         phase(shapes_phase, geo)
         phase(refine_phase, geo.store, args.seed)
-    phase(check_clean)
+    phase(check_clean, ONE_CHIP_OPS if args.chips == 1 else KERNEL_OPS)
     _log(f"total: {time.perf_counter() - t_all:.3f} s")
     if failures:
         return 1

@@ -403,7 +403,7 @@ _FRAGILE = {"kernel", "interpret", "cpu", "jit", "fused"}
 # stage -> {policy backend: (op whose breaker gates it, safe fallback)}
 _STAGE_DEMOTIONS = {
     "join": {"fused": ("fused_topk_join", "numpy"),
-             "kernel": ("distance_join_matrix", "numpy")},
+             "kernel": ("mbr_candidates", "numpy")},
     "rank": {"kernel": ("merge_join_ranks", "numpy"),
              "interpret": ("merge_join_ranks", "numpy"),
              "cpu": ("merge_join_ranks", "numpy")},

@@ -7,10 +7,14 @@ candidates — plus the exact-geometry refinement step.
 
 The MBR join is the compute hot spot. Three backends:
 
-- ``numpy``  — dense broadcast via geometry.box_min_dist; the portable
-  fallback and the oracle for tests.
-- ``kernel`` — the tiled Pallas matrix kernel (kernels/distance_join.py):
-  materializes the full (M, N) distance matrix, the caller masks it.
+- ``numpy``  — dense float64 broadcast via geometry.box_min_dist; the
+  portable fallback and the oracle for tests.
+- ``kernel`` — the device forms the candidate list (kernels/ops.py
+  `mbr_candidates`: a float32 test widened to a superset, compacted on
+  the device) and the host rechecks only those candidates in float64, so
+  the pairs equal numpy's element for element. Blocks of fewer than
+  `DEVICE_MIN_PAIRS` pairs stay on the numpy broadcast, which costs less
+  there than a dispatch. The "auto" choice on a TPU.
 - ``fused``  — the streaming top-k kernel (kernels/fused_topk_join.py):
   driven entities are fed in score-key order, each column batch is reduced
   in VMEM to per-row top-k partials under the current top-k threshold θ, and
@@ -28,17 +32,36 @@ import numpy as np
 from . import geometry, topk as topk_mod
 
 # Phase-3 MBR-join backend registry (see module docstring). "auto" resolves
-# to the dense numpy broadcast: the kernel path pays (M, N) materialization
-# through jax and the fused path only wins with real score keys + a live θ,
-# which the executor supplies explicitly when configured.
+# to the device route on a TPU and to the dense numpy broadcast elsewhere;
+# the fused path only wins with real score keys + a live θ, which the
+# executor supplies explicitly when configured.
 JOIN_BACKENDS = ("auto", "numpy", "kernel", "fused")
+
+# The "kernel" route's cut-over, in block-product pairs: a device join
+# costs about 3.9 ms however small the block, the numpy broadcast about
+# 10.9 ns a pair below a million pairs (TPU v5e host; PERF.md §6).
+DEVICE_MIN_PAIRS = 360_000
 
 
 def resolve_join_backend(backend: str | None) -> str:
     b = backend or "auto"
     if b not in JOIN_BACKENDS:
         raise ValueError(f"unknown spatial join backend {b!r}")
-    return "numpy" if b == "auto" else b
+    if b != "auto":
+        return b
+    from ..kernels import ops
+    return "kernel" if ops._on_tpu() else "numpy"
+
+
+@dataclasses.dataclass
+class PairCounters:
+    """Block-product pairs `mbr_distance_join` tested since the process
+    started, and how many of them the device tested."""
+    tested: int = 0
+    on_device: int = 0
+
+
+PAIRS = PairCounters()
 
 
 @dataclasses.dataclass
@@ -95,14 +118,18 @@ def mbr_distance_join(driver_boxes: np.ndarray, driven_boxes: np.ndarray,
                       dist_norm: float, backend: str = "numpy",
                       stats: JoinStats | None = None
                       ) -> tuple[np.ndarray, np.ndarray]:
-    """Candidate pairs (i, j) with box_min_dist <= dist (normalized space)."""
+    """Candidate pairs (i, j) with box_min_dist <= dist (normalized space),
+    in row-major order."""
     if len(driver_boxes) == 0 or len(driven_boxes) == 0:
         return np.empty(0, np.int64), np.empty(0, np.int64)
+    pairs = len(driver_boxes) * len(driven_boxes)
+    PAIRS.tested += pairs
     if backend == "fused":
         # pure-distance use of the streaming kernel: zero keys, θ = -inf.
         # With nothing to prune this does MORE work than the matrix paths —
         # it exists for drop-in equivalence (tests, ablations); the perf
         # path is fused_stream_join with real keys via the executor.
+        PAIRS.on_device += pairs
         pi, pj = [], []
         for bi, bj in fused_stream_join(
                 driver_boxes, driven_boxes,
@@ -114,19 +141,21 @@ def mbr_distance_join(driver_boxes: np.ndarray, driven_boxes: np.ndarray,
         j = np.concatenate(pj) if pj else np.empty(0, np.int64)
         order = np.lexsort((j, i))      # match the dense row-major order
         return i[order], j[order]
-    if backend == "kernel":
+    if backend == "kernel" and pairs >= DEVICE_MIN_PAIRS:
         from ..kernels import ops as kops
-        mask = np.asarray(kops.distance_join_mask(
-            driver_boxes.astype(np.float32), driven_boxes.astype(np.float32),
-            float(dist_norm)))
+        PAIRS.on_device += pairs
+        ci, cj = kops.mbr_candidates(driver_boxes, driven_boxes, dist_norm)
+        # the numpy route's own float64 test, on the device's candidates
+        keep = geometry.box_min_dist(driver_boxes[ci],
+                                     driven_boxes[cj]) <= dist_norm
+        i, j = ci[keep], cj[keep]
     else:
         d = geometry.box_min_dist(driver_boxes[:, None, :],
                                   driven_boxes[None, :, :])
-        mask = d <= dist_norm
+        i, j = np.nonzero(d <= dist_norm)
     if stats is not None:
-        stats.pairs_tested += mask.size
-        stats.candidates += int(mask.sum())
-    i, j = np.nonzero(mask)
+        stats.pairs_tested += pairs
+        stats.candidates += len(i)
     return i.astype(np.int64), j.astype(np.int64)
 
 

@@ -35,6 +35,7 @@ from . import distance_join as _dj
 from . import flash_attention as _fa
 from . import fused_topk_join as _ftj
 from . import geom_refine as _gr
+from . import mbr_candidates as _mc
 from . import merge_join as _mj
 from . import morton_kernel as _mk
 from . import ref
@@ -125,6 +126,132 @@ def distance_join_matrix(driver, driven, interpret: bool | None = None):
 def distance_join_mask(driver, driven, dist: float,
                        interpret: bool | None = None):
     return distance_join_matrix(driver, driven, interpret) <= dist
+
+
+# Padded block classes of `mbr_candidates`: rows pad to MBR_ROWS, columns
+# to a power of two in [MBR_MIN_COLS, MBR_MAX_COLS]; a larger block goes
+# in chunks of those sizes. The compaction's capacity is a power of two
+# from MBR_MIN_CAP up. That makes one test program per column class and
+# one compaction program per capacity.
+MBR_ROWS = 1024
+MBR_MIN_COLS = 1024
+MBR_MAX_COLS = 16384
+MBR_MIN_CAP = 1024
+_F32_EPS = 2.0 ** -24          # unit roundoff of float32
+
+
+def mbr_threshold32(driver: np.ndarray, driven: np.ndarray,
+                    dist: float) -> np.float32:
+    """Squared float32 threshold for the device's MBR test.
+
+    Every pair whose float64 `geometry.box_min_dist` is <= `dist` passes
+    ``dx² + dy² <= t`` when the boxes are rounded to float32 and the test
+    runs in float32. With u the float32 unit roundoff and C the largest
+    finite coordinate magnitude, rounding the coordinates and subtracting
+    moves dx and dy by at most e = 4uC(1 + u); squaring and summing adds
+    under three roundings more. So t is (dist·(1 + 4u) + 2e)²·(1 + 4u),
+    rounded up, and never below the smallest normal float32 (a device may
+    flush subnormals). The factor on `dist` also covers boxes handed in
+    float32, whose numpy test rounds in float32 too.
+    """
+    c = 0.0
+    for a in (driver, driven):
+        a = np.abs(a[np.isfinite(a)])
+        if a.size:
+            c = max(c, float(a.max()))
+    e = 4.0 * _F32_EPS * c * (1.0 + _F32_EPS)
+    t = (float(dist) * (1.0 + 4.0 * _F32_EPS) + 2.0 * e) ** 2 \
+        * (1.0 + 4.0 * _F32_EPS)
+    t32 = np.float32(t)
+    if float(t32) < t:
+        t32 = np.nextafter(t32, np.float32(np.inf))
+    return max(t32, np.finfo(np.float32).tiny)
+
+
+def mbr_candidates(driver, driven, dist: float):
+    """Phase-3 MBR candidate pairs, tested and compacted on the device.
+
+    driver (M, 4) / driven (N, 4) boxes (x0, y0, x1, y1), float64. Returns
+    host int64 (i, j) in row-major order: every pair whose float64
+    `geometry.box_min_dist` is <= `dist`, and the few more that pass the
+    widened float32 test (`mbr_threshold32`) — the caller rechecks them in
+    float64. The device never hands back the (M, N) matrix or mask: each
+    block chunk comes back as its count and then its candidates' flat
+    positions, 4 bytes each in a power-of-two capacity
+    (kernels/mbr_candidates.py). The jnp oracle fetches the dense mask; it
+    also takes a chunk with more candidates than its mask has words.
+    """
+    driver = np.asarray(driver)
+    driven = np.asarray(driven)
+    m, n = len(driver), len(driven)
+    empty = np.empty(0, np.int64)
+    if m == 0 or n == 0:
+        return empty, empty
+    thresh = mbr_threshold32(driver, driven, dist)
+    drv32 = driver.astype(np.float32)
+    dvn32 = driven.astype(np.float32)
+    pi, pj = [], []
+    with _Dispatch("mbr_candidates") as call:
+        for c0 in range(0, n, MBR_MAX_COLS):
+            nc = min(MBR_MAX_COLS, n - c0)
+            ncols = max(MBR_MIN_COLS, 1 << int(nc - 1).bit_length())
+            shift = ncols.bit_length() - 1
+            dvn_t = np.zeros((4, ncols), np.float32)
+            dvn_t[:, :nc] = dvn32[c0:c0 + nc].T
+            dvn_dev = call.up(dvn_t)
+            for r0 in range(0, m, MBR_ROWS):
+                mr = min(MBR_ROWS, m - r0)
+                drv = np.zeros((MBR_ROWS, 4), np.float32)
+                drv[:mr] = drv32[r0:r0 + mr]
+                flat = _mbr_chunk(call, drv, dvn_t, dvn_dev, mr, nc, thresh)
+                pi.append(r0 + (flat >> shift))
+                pj.append(c0 + (flat & (ncols - 1)))
+    i, j = np.concatenate(pi), np.concatenate(pj)
+    if n > MBR_MAX_COLS:
+        # chunk by chunk, each row-major: a stable sort on the row restores
+        # the row-major order of the whole block
+        order = np.argsort(i, kind="stable")
+        i, j = i[order], j[order]
+    return i, j
+
+
+def _mbr_chunk(call: _Dispatch, drv, dvn_t, dvn_dev, m: int, n: int,
+               thresh) -> np.ndarray:
+    """One padded chunk's candidates as int64 flat positions of the
+    (MBR_ROWS, ncols) block, through the failover chain."""
+    n_words = drv.shape[0] * dvn_t.shape[1] // 32
+
+    def oracle():
+        mask = call.down(_mbr_mask_jit(call.up(drv), dvn_dev, thresh))
+        i, j = np.nonzero(mask[:m, :n])
+        return i.astype(np.int64) * dvn_t.shape[1] + j
+
+    def device():
+        count, words, excl, slot_word = _mc.count_words(
+            call.up(drv), dvn_dev, m, n, thresh)
+        c = int(call.down(count))
+        if c == 0:
+            return np.empty(0, np.int64)
+        if c > n_words:     # no room in the slot plane
+            return oracle()
+        cap = max(MBR_MIN_CAP, 1 << int(c - 1).bit_length())
+        flat = call.down(_mc.compact(words, excl, slot_word, cap=cap))
+        return flat[:c].astype(np.int64)
+
+    live = "kernel" if _on_tpu() else "jit"
+    return _fault.run_op(
+        "mbr_candidates", [(live, device), ("oracle", oracle)],
+        validate=functools.partial(_v_flat, m=m, n=n, ncols=dvn_t.shape[1]))
+
+
+_mbr_mask_jit = jax.jit(ref.mbr_mask_ref)
+
+
+def _v_flat(out, m: int, n: int, ncols: int) -> bool:
+    f = np.asarray(out)
+    return bool(f.size == 0 or (
+        (np.diff(f) > 0).all() and f[0] >= 0
+        and (f // ncols < m).all() and (f % ncols < n).all()))
 
 
 def fused_topk_join(driver, driven, driver_keys, driven_keys,

@@ -22,6 +22,17 @@ def distance_join_ref(driver: jnp.ndarray, driven: jnp.ndarray) -> jnp.ndarray:
     return jnp.sqrt(dx * dx + dy * dy).astype(jnp.float32)
 
 
+def mbr_mask_ref(driver: jnp.ndarray, driven_t: jnp.ndarray,
+                 thresh) -> jnp.ndarray:
+    """Squared-distance MBR test: driver (M, 4) and transposed driven
+    (4, N) float32 boxes -> (M, N) bool, dx² + dy² <= thresh."""
+    ax0, ay0, ax1, ay1 = (driver[:, c:c + 1] for c in range(4))
+    bx0, by0, bx1, by1 = (driven_t[c:c + 1, :] for c in range(4))
+    dx = jnp.maximum(0.0, jnp.maximum(ax0 - bx1, bx0 - ax1))
+    dy = jnp.maximum(0.0, jnp.maximum(ay0 - by1, by0 - ay1))
+    return dx * dx + dy * dy <= thresh
+
+
 # ------------------------------------------------- fused top-k distance join --
 def fused_topk_join_ref(driver: jnp.ndarray, driven: jnp.ndarray,
                         driver_keys: jnp.ndarray, driven_keys: jnp.ndarray,

@@ -249,13 +249,16 @@ class SpatialServeEngine:
             return n
 
     def counters(self) -> dict:
-        """Share-cache lookups and hits (every kind together) and the
-        bytes the kernel dispatches uploaded and fetched, so far."""
+        """Share-cache lookups and hits (every kind together), the bytes
+        the kernel dispatches uploaded and fetched, and the Phase-3
+        block-product pairs tested and tested on the device, so far."""
         sc, fs = self.engine.share_cache, fault.STATE.stats
         return {"share_lookups": sum(sc.lookups.values()),
                 "share_hits": sum(sc.hits.values()),
                 "h2d_bytes": sum(fs.h2d_bytes.values()),
-                "d2h_bytes": sum(fs.d2h_bytes.values())}
+                "d2h_bytes": sum(fs.d2h_bytes.values()),
+                "mbr_pairs": spatial_join.PAIRS.tested,
+                "mbr_device_pairs": spatial_join.PAIRS.on_device}
 
     def _step(self) -> int:
         self._tick += 1

@@ -15,7 +15,7 @@ import time
 import numpy as np
 import pytest
 
-from repro.core import fault
+from repro.core import fault, spatial_join
 from repro.core.baselines import FullScanEngine
 from repro.core.executor import ExecConfig, StreakEngine
 from repro.core.policy import BackendPolicy
@@ -57,7 +57,7 @@ def _assert_same(a, b):
 # ------------------------------------------------------- kernel failover ---
 # each instrumented op, with a policy whose plan actually dispatches it
 OP_CONFIGS = [
-    ("distance_join_matrix", BackendPolicy(join="kernel")),
+    ("mbr_candidates", BackendPolicy(join="kernel")),
     ("fused_topk_join", BackendPolicy(join="fused")),
     ("bucketed_min_core", BackendPolicy()),
     ("merge_join_ranks", BackendPolicy(impl="merge")),
@@ -67,7 +67,10 @@ OP_CONFIGS = [
 
 
 @pytest.mark.parametrize("op,policy", OP_CONFIGS, ids=[o for o, _ in OP_CONFIGS])
-def test_each_op_failing_once_is_bit_identical(lgd, op, policy):
+def test_each_op_failing_once_is_bit_identical(lgd, op, policy,
+                                               monkeypatch):
+    # the tiny store's join blocks would all stay on the host
+    monkeypatch.setattr(spatial_join, "DEVICE_MIN_PAIRS", 0)
     q = lgd.queries[0]
     want = _run(lgd, q, policy=policy)
     plan = FaultPlan(rules=(FaultRule(op=op, call=0),))
@@ -141,12 +144,13 @@ def test_corrupt_then_detect_recovers_bit_identical(lgd):
     _assert_same(got, want)
 
 
-def test_watchdog_timeout_falls_back_bit_identical(lgd):
+def test_watchdog_timeout_falls_back_bit_identical(lgd, monkeypatch):
+    monkeypatch.setattr(spatial_join, "DEVICE_MIN_PAIRS", 0)
     q = lgd.queries[0]
     pol = BackendPolicy(join="kernel")
     want = _run(lgd, q, policy=pol)
     plan = FaultPlan(rules=(
-        FaultRule(op="distance_join_matrix", call=0, mode="delay",
+        FaultRule(op="mbr_candidates", call=0, mode="delay",
                   delay_s=0.5),))
     with fault.fault_plan(plan), fault.watchdog(0.05):
         got = _run(lgd, q, policy=pol)
@@ -204,6 +208,23 @@ def test_open_breaker_demotes_policy_resolution():
     assert BackendPolicy(probe="kernel").resolve().probe == "kernel"
     fault.STATE.reset()
     assert BackendPolicy(descend="kernel").resolve().descend == "kernel"
+
+
+def test_open_mbr_breaker_demotes_the_join_stage():
+    """The join stage's `kernel` route rides on `mbr_candidates`: a breaker
+    open there reroutes later plans to the numpy join."""
+    from repro.kernels import ops
+    boxes = np.zeros((2, 4))
+    plan = FaultPlan(rules=(FaultRule(op="mbr_candidates", attempts=99),))
+    with fault.fault_plan(plan):
+        for _ in range(fault.STATE.breaker_threshold):
+            with pytest.raises(fault.FallbackExhausted):
+                ops.mbr_candidates(boxes, boxes, 0.1)
+    assert fault.STATE.breaker("mbr_candidates", "jit").open
+    assert BackendPolicy(join="kernel").resolve().join == "numpy"
+    assert fault.STATE.stats.policy_demotions > 0
+    fault.STATE.reset()
+    assert BackendPolicy(join="kernel").resolve().join == "kernel"
 
 
 # ----------------------------------------------------- deadlines / anytime --

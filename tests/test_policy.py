@@ -29,6 +29,17 @@ def test_resolve_pins_autos_and_is_idempotent():
     assert p.resolve() == p             # idempotent
 
 
+def test_auto_join_resolves_to_the_device_route_on_a_tpu(monkeypatch):
+    from repro.core import spatial_join
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_auto_rank_backend", None)
+    assert spatial_join.resolve_join_backend("auto") == "kernel"
+    assert spatial_join.resolve_join_backend(None) == "kernel"
+    assert BackendPolicy().resolve().join == "kernel"
+    assert BackendPolicy(join="numpy").resolve().join == "numpy"
+
+
 def test_resolve_keeps_explicit_choices():
     p = BackendPolicy(join="fused", impl="looped", rank="interpret",
                       probe="kernel", descend="interpret",

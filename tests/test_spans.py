@@ -80,11 +80,12 @@ def _host_spans(path) -> list:
 @pytest.fixture(scope="module")
 def traced(lgd, tenants, tmp_path_factory):
     out = tmp_path_factory.mktemp("trace")
-    _serve(lgd.store, tenants)          # compile outside the trace
+    warm, _ = _serve(lgd.store, tenants)    # compile outside the trace
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
-    moved = ("h2d_bytes", "d2h_bytes")     # process-wide, unlike the rest
-    before = {k: sum(getattr(fault.STATE.stats, k).values()) for k in moved}
+    # process-wide, unlike the share cache's
+    moved = ("h2d_bytes", "d2h_bytes", "mbr_pairs", "mbr_device_pairs")
+    before = warm.counters()
     jax.profiler.start_trace(str(out), profiler_options=opts)
     try:
         srv, reqs = _serve(lgd.store, tenants)

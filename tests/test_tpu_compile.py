@@ -1,5 +1,6 @@
 """Compile the main-path Pallas kernels for a TPU v5e that is described,
-not attached, at the engine's real sizes and tile defaults.
+not attached, at the engine's real sizes and tile defaults, and the
+device join's XLA programs (kernels/mbr_candidates.py) at their classes.
 
 Interpret mode (the rest of the suite) cannot see what the TPU compiler
 refuses: unaligned blocks, ops with no Mosaic lowering, tiles past the
@@ -15,7 +16,8 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels import (bloom_probe, distance_join, fused_topk_join,
-                           geom_refine, merge_join, ops, tree_descend)
+                           geom_refine, mbr_candidates, merge_join, ops,
+                           tree_descend)
 
 F32, I32, U32 = jnp.float32, jnp.int32, jnp.uint32
 
@@ -105,6 +107,21 @@ def test_merge_join_ranks_compiles(shape):
     _assert_kernel(merge_join.merge_join_ranks.lower(
         shape((t,), I32), shape((t,), I32), shape((p,), I32),
         shape((p,), I32)))
+
+
+@pytest.mark.parametrize("ncols", [ops.MBR_MIN_COLS, ops.MBR_MAX_COLS])
+def test_mbr_count_words_compiles(shape, ncols):
+    mbr_candidates.count_words.lower(
+        shape((ops.MBR_ROWS, 4), F32), shape((4, ncols), F32),
+        shape((), I32), shape((), I32), shape((), F32)).compile()
+
+
+@pytest.mark.parametrize("cap", [ops.MBR_MIN_CAP, mbr_candidates.MAX_WORDS])
+def test_mbr_compact_compiles(shape, cap):
+    w = mbr_candidates.MAX_WORDS
+    mbr_candidates.compact.lower(
+        shape((w,), U32), shape((w,), I32), shape((w,), I32),
+        cap=cap).compile()
 
 
 def test_sharded_descent_compiles_over_four_chips(topo):
