@@ -4,7 +4,8 @@ Everything that belongs to one configuration, traffic mix or metric is
 found by its name in BENCHMARK.json:
 
 - the configuration's ``file`` (JSON) holds its sizes and names its
-  generator, ``datagen/<generator>.py``;
+  generator, ``datagen/<generator>.py`` (a ``rehearsal`` key, the CPU
+  tests' cut, is not read here);
 - the traffic mix is ``traffic/<traffic>.json``, read by `traffic.Mix`;
 - each metric is read by ``metrics/<name>.py``, or, for a name with a
   suffix after a dot (``step_ms.lat``), by ``metrics/<name before the
@@ -17,7 +18,6 @@ key of the result.
 from __future__ import annotations
 
 import dataclasses
-import importlib
 import importlib.util
 import json
 import os
@@ -50,6 +50,7 @@ class Cell:
     name: str
     chips: int
     config: dict           # the configuration's file, parsed
+    gen: object            # the configuration's generator module
     mix: traffic.Mix
     metrics: dict          # name -> (entry, reader) for --trace 0 / 1
     traced_metrics: dict
@@ -68,6 +69,20 @@ def _reader(root: Path, name: str):
     raise CellError(f"no reader for metric {name!r} under {mdir}")
 
 
+def _generator(root: Path, name: str):
+    """``datagen/<name>.py`` under `root`, loaded as a module of this
+    package's ``datagen``, so that its sibling imports (``.common``)
+    resolve."""
+    path = root / BENCH_DIR.name / "datagen" / f"{name}.py"
+    if not path.is_file():
+        raise CellError(f"no generator {name!r} under {path.parent}")
+    spec = importlib.util.spec_from_file_location(
+        f"{BENCH_DIR.name}.datagen.{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def _reports(entry: dict, cell: str) -> bool:
     return "workloads" not in entry or cell in entry["workloads"]
 
@@ -79,13 +94,14 @@ def load_cell(root: Path, bench: dict, name: str) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     cfg = json.loads((root / configs[w["config"]]["file"]).read_text())
+    gen = _generator(root, cfg["generator"])
     mix = traffic.Mix.load(root / BENCH_DIR.name / "traffic"
                            / f"{w['traffic']}.json")
     e2e = {m["name"]: (m, _reader(root, m["name"]))
            for m in bench["end_to_end"] if _reports(m, name)}
     layer = {m["name"]: (m, _reader(root, m["name"]))
              for m in bench["per_layer"] if _reports(m, name)}
-    return Cell(name, int(w["chips"]), cfg, mix, e2e, layer)
+    return Cell(name, int(w["chips"]), cfg, gen, mix, e2e, layer)
 
 
 def _devices(chips: int, require_tpu: bool):
@@ -178,7 +194,6 @@ class Prepared:
     cache: str
     compiles: CompileCounter
     program: object         # the benchmark's adapter module
-    gen: object             # the configuration's generator module
     raw: object
     store: object
     eng: object
@@ -198,9 +213,7 @@ def prepare(root: Path, bench: dict, name: str, seed: int, t_start: float,
     t_jax = clock()
     from . import program
 
-    cfg, mix = cell.config, cell.mix
-    gen = importlib.import_module(
-        f"{BENCH_DIR.name}.datagen.{cfg['generator']}")
+    cfg, gen, mix = cell.config, cell.gen, cell.mix
     setup: dict = {"jax_start_s": t_jax - t_start}
     t = clock()
     raw = relabel(gen.generate(cfg, int(cfg["data_seed"])), seed)
@@ -224,8 +237,8 @@ def prepare(root: Path, bench: dict, name: str, seed: int, t_start: float,
     setup["walk_s"] = clock() - t
     warm_fail = [program.request_state(r) for r in warm
                  if program.request_state(r) != "ok"]
-    return Prepared(cell, devs, cache, compiles, program, gen, raw, store,
-                    eng, make, setup, warm_fail, store_bytes)
+    return Prepared(cell, devs, cache, compiles, program, raw, store, eng,
+                    make, setup, warm_fail, store_bytes)
 
 
 def run(root: Path, bench: dict, name: str, seed: int, seconds: float,
@@ -236,7 +249,7 @@ def run(root: Path, bench: dict, name: str, seed: int, seconds: float,
     to show that the comparison fails it."""
     p = prepare(root, bench, name, seed, t_start, require_tpu)
     cell, devs, compiles, program = p.cell, p.devs, p.compiles, p.program
-    gen, raw, eng, setup = p.gen, p.raw, p.eng, p.setup
+    gen, raw, eng, setup = cell.gen, p.raw, p.eng, p.setup
     mix = cell.mix
     made0 = compiles.snapshot()
 

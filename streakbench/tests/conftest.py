@@ -1,10 +1,11 @@
 """A tiny copy of the benchmark to rehearse runs on the CPU.
 
 `tiny_root` is a checkout-shaped directory: BENCHMARK.json with the real
-cells, each configuration cut to a few thousand quads, and the real
-traffic mixes and metric readers. `interpreted_chip` steers the program's
-kernel dispatch onto its TPU branch with every Pallas kernel in interpret
-mode, so a run takes the kernels' path as it does on the chip.
+cells, the real configurations, generators, traffic mixes and metric
+readers, and each configuration cut to the sizes under its own
+``rehearsal`` key (a few thousand quads). `interpreted_chip` steers the
+program's kernel dispatch onto its TPU branch with every Pallas kernel in
+interpret mode, so a run takes the kernels' path as it does on the chip.
 
 Run from the repository root: ``python -m pytest streakbench/tests``.
 """
@@ -20,26 +21,30 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-TINY = {"lgd_scale_1m": {"n_quads": 4000, "block": 128},
-        "yago3_20k": {"n_places": 600, "block": 128}}
-
-
-def make_root(tmp: Path, mixes: dict | None = None,
-              cells: list | None = None) -> Path:
-    """A checkout-shaped directory with tiny configurations; `mixes` adds
-    traffic files, `cells` adds BENCHMARK.json workloads."""
+def make_root(tmp: Path, files: dict | None = None,
+              entries: dict | None = None) -> Path:
+    """A checkout-shaped directory with tiny configurations. `files` (path
+    under the root -> text) adds files, `entries` (key -> list) appends to
+    BENCHMARK.json's lists; then every configuration is cut to the sizes
+    under its ``rehearsal`` key."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    sb = tmp / "streakbench"
-    shutil.copytree(ROOT / "streakbench" / "metrics", sb / "metrics")
-    shutil.copytree(ROOT / "streakbench" / "traffic", sb / "traffic")
-    (sb / "configs").mkdir()
+    for part in ("configs", "datagen", "metrics", "traffic"):
+        shutil.copytree(ROOT / "streakbench" / part,
+                        tmp / "streakbench" / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    for rel, text in (files or {}).items():
+        (tmp / rel).parent.mkdir(parents=True, exist_ok=True)
+        (tmp / rel).write_text(text)
+    for key, more in (entries or {}).items():
+        bench[key] += more
     for c in bench["configs"]:
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        cfg.update(TINY[c["name"]])
-        (tmp / c["file"]).write_text(json.dumps(cfg))
-    for name, mix in (mixes or {}).items():
-        (sb / "traffic" / f"{name}.json").write_text(json.dumps(mix))
-    bench["workloads"] += cells or []
+        path = tmp / c["file"]
+        cfg = json.loads(path.read_text())
+        if "rehearsal" not in cfg:
+            raise ValueError(f"{c['file']}: no 'rehearsal' key, the sizes "
+                             "the CPU rehearsal cuts this configuration to")
+        cfg.update(cfg["rehearsal"])
+        path.write_text(json.dumps(cfg))
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp
 

@@ -133,18 +133,147 @@ def test_a_mix_a_cell_and_a_metric_are_added_as_files_only(tmp_path):
            "ks": [7, 30], "warmup_requests": 2, "drain_s": 30}
     cell = {"name": "lgd1m.throwaway", "config": "lgd_scale_1m",
             "traffic": "throwaway", "chips": 1, "why": "test"}
-    root = make_root(tmp_path, mixes={"throwaway": mix}, cells=[cell])
-    (root / "streakbench" / "metrics" / "answered_share.py").write_text(
-        "def read(rec):\n"
-        "    return 100.0 * len(rec.answered()) / len(rec.requests)\n")
-    bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["end_to_end"].append(
-        {"name": "answered_share", "unit": "%", "better": "higher",
-         "bound": 0.01, "source": "host_clock",
-         "workloads": ["lgd1m.throwaway"]})
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    metric = {"name": "answered_share", "unit": "%", "better": "higher",
+              "bound": 0.01, "source": "host_clock",
+              "workloads": ["lgd1m.throwaway"]}
+    root = make_root(tmp_path, files={
+        "streakbench/traffic/throwaway.json": json.dumps(mix),
+        "streakbench/metrics/answered_share.py":
+            "def read(rec):\n"
+            "    return 100.0 * len(rec.answered()) / len(rec.requests)\n"},
+        entries={"workloads": [cell], "end_to_end": [metric]})
     out = _run(root, "lgd1m.throwaway")
     assert out["correct"], out["check"]
     assert out["metrics"]["answered_share"]["value"] == 100.0
     # end-to-end metrics without a "workloads" list reach every cell
     assert {"bytes_per_quad", "setup_s"} <= set(out["metrics"])
+
+
+# A deployment of ways, as a later configuration would bring it: polylines
+# of heavy-tailed vertex counts, one of them past the program's refinement
+# size class of 128 points, beside single points; reified type facts with a
+# confidence, and the LGD pair query between the two classes.
+WAYS_GENERATOR = '''
+import numpy as np
+
+from .common import RawData
+
+EXTENT = 100.0
+TERMS = ("rdf:type", "hasGeometry", "hasConfidence", "class:way",
+         "class:poi")
+
+
+def generate(cfg, seed):
+    n = int(cfg["n_entities"])
+    rng = np.random.default_rng(seed)
+    terms = {t: i + 1 for i, t in enumerate(TERMS)}
+    numeric = {}
+    conf_ids = []
+    for v in np.round(np.linspace(0.0, 1.0, 64), 6):
+        terms[repr(float(v))] = len(terms) + 1
+        numeric[terms[repr(float(v))]] = float(v)
+        conf_ids.append(terms[repr(float(v))])
+    ent = len(terms) + 1 + np.arange(n, dtype=np.int64)
+    fact, geo = ent + n, ent + 2 * n
+    is_way = np.arange(n) % 2 == 0
+    counts = np.where(is_way, np.minimum(1 + rng.zipf(1.5, n), 200), 1)
+    counts[0] = 300
+    walks = [np.cumsum(np.concatenate(
+        [rng.uniform(0.0, EXTENT, (1, 2)),
+         rng.normal(0.0, 0.4, (c - 1, 2))]), axis=0) for c in counts]
+    points = np.clip(np.concatenate(walks), 0.0, EXTENT)
+    offsets = np.concatenate([[0], np.cumsum(counts)])
+    boxes = np.array([np.concatenate([points[a:b].min(0), points[a:b].max(0)])
+                      for a, b in zip(offsets[:-1], offsets[1:])])
+    cls = np.where(is_way, terms["class:way"], terms["class:poi"])
+    conf = np.asarray(conf_ids)[rng.integers(0, len(conf_ids), n)]
+    zeros = np.zeros(n, dtype=np.int64)
+    quads = np.concatenate([
+        np.stack([zeros, ent, np.full(n, terms["hasGeometry"]), geo], 1),
+        np.stack([fact, ent, np.full(n, terms["rdf:type"]), cls], 1),
+        np.stack([zeros, fact, np.full(n, terms["hasConfidence"]), conf], 1),
+    ]).astype(np.int64)
+    return RawData(
+        quads=quads, terms=terms, numeric=numeric, next_id=int(geo[-1]) + 1,
+        geometry_predicate=terms["hasGeometry"], geom_entities=ent,
+        geom_boxes=boxes, geom_offsets=offsets, geom_points=points,
+        exact=True)
+
+
+def query(data, spec, k):
+    t = data.terms
+    return {
+        "patterns": [
+            ["?r", "?place", "?typePred1", t[spec["a"]]],
+            [None, "?r", t["hasConfidence"], "?conf"],
+            [None, "?place", t["hasGeometry"], "?g1"],
+            ["?r1", "?nplace", "?typePred2", t[spec["b"]]],
+            [None, "?r1", t["hasConfidence"], "?conf1"],
+            [None, "?nplace", t["hasGeometry"], "?g2"],
+        ],
+        "spatial": ["?g1", "?g2", EXTENT * float(spec["dist_frac"])],
+        "rank": [["?conf", 1.0], ["?conf1", 1.0]],
+        "descending": False,
+        "k": int(k),
+    }
+'''
+
+WAYS_CONFIG = {
+    "name": "throwaway_ways", "generator": "throwaway_ways", "data_seed": 0,
+    "n_entities": 100000, "l_max": 6, "leaf_capacity": 32, "block": 1024,
+    "max_slots": 4, "rehearsal": {"n_entities": 300, "block": 128}}
+
+
+def _ways_root(tmp_path, config=WAYS_CONFIG):
+    mix = {"loop": "closed", "clients": 4,
+           "queries": [{"a": "class:way", "b": "class:poi"},
+                       {"a": "class:poi", "b": "class:way"},
+                       {"a": "class:way", "b": "class:way"}],
+           "ks": [5, 20], "warmup_requests": 6, "drain_s": 60,
+           "vary": {"dist_frac": {"geomspace": [0.005, 0.02], "n": 16}}}
+    cfg = {"name": "throwaway_ways", "source": "test",
+           "file": "streakbench/configs/throwaway_ways.json",
+           "reduced": [], "why": "test"}
+    cell = {"name": "ways.throwaway", "config": "throwaway_ways",
+            "traffic": "throwaway_ways", "chips": 1, "why": "test"}
+    return make_root(tmp_path, files={
+        "streakbench/configs/throwaway_ways.json": json.dumps(config),
+        "streakbench/datagen/throwaway_ways.py": WAYS_GENERATOR,
+        "streakbench/traffic/throwaway_ways.json": json.dumps(mix)},
+        entries={"configs": [cfg], "workloads": [cell]})
+
+
+def test_a_configuration_is_added_as_files_only(tmp_path, monkeypatch,
+                                                interpreted_chip):
+    """A throwaway configuration, its generator, a mix and a cell: new files
+    and new BENCHMARK.json entries only. Its exact geometries reach the
+    refinement kernel, fragmented past 128 points; the bfloat16 control
+    fails the same comparison."""
+    from repro.core import fault, spatial_join
+    widest = []
+    pool_min_dist = spatial_join.pool_min_dist
+
+    def spy(pool, rows_a, rows_b, *args, **kwargs):
+        widest.append(int(max(pool.counts(np.asarray(rows_a)).max(),
+                              pool.counts(np.asarray(rows_b)).max())))
+        return pool_min_dist(pool, rows_a, rows_b, *args, **kwargs)
+    monkeypatch.setattr(spatial_join, "pool_min_dist", spy)
+
+    root = _ways_root(tmp_path)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    cell = harness.load_cell(root, bench, "ways.throwaway")
+    assert cell.config["n_entities"] == 300      # the rehearsal cut
+    out = _run(root, "ways.throwaway")
+    assert out["correct"], out["check"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert fault.STATE.stats.calls[("bucketed_min_core", "kernel")] > 0
+    assert max(widest) > spatial_join.REFINE_MAX_PTS
+    out = _run(root, "ways.throwaway", control=True)
+    assert not out["correct"]
+    assert out["check"]["wrong_answers"]["value"] > 0
+
+
+def test_a_configuration_without_a_rehearsal_cut_is_named(tmp_path):
+    config = {k: v for k, v in WAYS_CONFIG.items() if k != "rehearsal"}
+    with pytest.raises(ValueError, match="throwaway_ways.json"):
+        _ways_root(tmp_path, config)
