@@ -500,12 +500,65 @@ def fused_topk_pairs(driver_boxes: np.ndarray, driven_boxes: np.ndarray,
 # ---------------------------------------------------------------------------
 
 REFINE_MAX_PTS = 128        # size-class cap; larger geometries are fragmented
+# A size class's pairs go to the device in tiles of a fixed number of pairs,
+# about REFINE_TILE_POINTS points a tile over both sides, at least the
+# kernel's 256 lanes and at most 4096 pairs; so refinement makes one
+# program per (m_pad, n_pad) class whatever the data, 64 at most.
+REFINE_TILE_POINTS = 1 << 15
+
+
+@dataclasses.dataclass
+class RefineCounters:
+    """Exact refinement done by `pool_min_dist` since the process started:
+    geometry pairs, their fragment pairs, the vertex pairs the kernel
+    computed (the padded tile area, m_pad · n_pad a tile row) and the
+    kernel launches."""
+    pairs: int = 0
+    fragment_pairs: int = 0
+    vertex_pairs: int = 0
+    launches: int = 0
+
+
+REFINE = RefineCounters()
 
 
 def _size_class(n: np.ndarray) -> np.ndarray:
     """Next power of two >= n (n in [1, REFINE_MAX_PTS])."""
     return (1 << np.ceil(np.log2(np.maximum(n, 1))).astype(np.int64)) \
         .astype(np.int64)
+
+
+def refine_tile(m_pad: int, n_pad: int) -> int:
+    """Pairs in one refinement launch of size class (m_pad, n_pad)."""
+    t = 1 << (REFINE_TILE_POINTS // (m_pad + n_pad)).bit_length() - 1
+    return min(max(t, 256), 4096)
+
+
+def refine_classes(pool) -> list:
+    """The size classes of the fragments of `pool`'s geometries: every
+    m_pad (and n_pad) a refinement over the pool can launch."""
+    cnt = np.diff(pool.offsets)
+    rest = cnt % REFINE_MAX_PTS
+    lens = np.concatenate([rest[rest > 0],
+                           [REFINE_MAX_PTS] if cnt.max() >= REFINE_MAX_PTS
+                           else []])
+    return sorted({int(c) for c in _size_class(lens.astype(np.int64))})
+
+
+def compile_refine(pool) -> int:
+    """Make the euclidean refinement programs `pool` can need, one launch
+    of each (m_pad, n_pad) class on a throwaway tile, so that none is made
+    while queries are served. Returns the launches made."""
+    from ..kernels import ops as kops
+
+    classes = refine_classes(pool)
+    for m_pad in classes:
+        for n_pad in classes:
+            t = refine_tile(m_pad, n_pad)
+            kops.bucketed_min_core(
+                (np.zeros((t, m_pad), np.float32),) * 2,
+                (np.zeros((t, n_pad), np.float32),) * 2)
+    return len(classes) ** 2
 
 
 def core_to_dist(core: np.ndarray, metric: str) -> np.ndarray:
@@ -531,15 +584,18 @@ def pool_min_dist(pool, rows_a: np.ndarray, rows_b: np.ndarray,
 
     Vectorized replacement for the per-pair python loop: pairs are grouped by
     padded (m_pad, n_pad) size class (next power of two per side), each
-    bucket is gathered from the CSR pool's coordinate planes — raw x/y for
-    euclid, unit-sphere X/Y/Z for haversine (chord² = 4h, trig hoisted to
-    pool build) — into dense (B, m_pad) / (B, n_pad) tiles, padding
-    replicating the entity's last point (which can never change a min), and
-    one kernel call per bucket computes the pairwise minima
-    (kernels/geom_refine.py; jnp oracle on CPU). Geometries wider than
-    `max_pts` are fragmented into <= max_pts chunks on both sides (min
-    distance decomposes over point subsets) and min-scattered back.
-    Returns (n_pairs,) float64 distances (f32 cores, f64 final transform).
+    class's pairs are gathered from the CSR pool's coordinate planes — raw
+    x/y for euclid, unit-sphere X/Y/Z for haversine (chord² = 4h, trig
+    hoisted to pool build) — into dense (T, m_pad) / (T, n_pad) tiles of
+    the class's fixed `refine_tile` T pairs, padding replicating the
+    entity's last point (which can never change a min), and one kernel call
+    per tile computes the pairwise minima (kernels/geom_refine.py; jnp
+    oracle on CPU). A class's last tile is filled by replaying its first
+    pair: min-scatter is idempotent. Geometries wider than `max_pts` are
+    fragmented into <= max_pts chunks on both sides (min distance
+    decomposes over point subsets) and min-scattered back. Counts its work
+    on `REFINE`. Returns (n_pairs,) float64 distances (f32 cores, f64 final
+    transform).
     """
     from ..kernels import ops as kops
 
@@ -567,32 +623,30 @@ def pool_min_dist(pool, rows_a: np.ndarray, rows_b: np.ndarray,
         len_a = np.minimum(cnt_a[pair_idx] - ca * max_pts, max_pts)
         start_b = off[rows_b][pair_idx] + cb * max_pts
         len_b = np.minimum(cnt_b[pair_idx] - cb * max_pts, max_pts)
+    REFINE.pairs += npairs
+    REFINE.fragment_pairs += len(pair_idx)
     cls_a, cls_b = _size_class(len_a), _size_class(len_b)
     planes = pool.planes3d() if metric == "haversine" else pool.planes2d()
     key = cls_a * (2 * max_pts) + cls_b
     for kk in np.unique(key):
         sel = np.flatnonzero(key == kk)
         m_pad, n_pad = int(cls_a[sel[0]]), int(cls_b[sel[0]])
-        # pad the batch axis to a bounded shape family too: bucket sizes
-        # are data-dependent, and unpadded they would jit-compile a fresh
-        # kernel per distinct size. Rounding up at 3-significant-bit
-        # granularity keeps <= 8 shapes per power of two and <= ~14% pad
-        # waste. Padding replays the first fragment — min-scatter is
-        # idempotent, so duplicates are harmless.
-        blen = len(sel)
-        g = 64 if blen <= 64 else 1 << max(6, blen.bit_length() - 3)
-        bpad = -(-blen // g) * g
-        sel = np.concatenate([sel, np.full(bpad - blen, sel[0],
-                                           dtype=np.int64)])
-        # clamped gather: index min(arange, len-1) replicates the last point
-        ia = start_a[sel, None] + np.minimum(np.arange(m_pad)[None, :],
-                                             (len_a[sel] - 1)[:, None])
-        ib = start_b[sel, None] + np.minimum(np.arange(n_pad)[None, :],
-                                             (len_b[sel] - 1)[:, None])
-        c = np.asarray(kops.bucketed_min_core(
-            tuple(p[ia] for p in planes), tuple(p[ib] for p in planes),
-            interpret=interpret))
-        np.minimum.at(out, pair_idx[sel], c)
+        tile = refine_tile(m_pad, n_pad)
+        for t0 in range(0, len(sel), tile):
+            part = sel[t0:t0 + tile]
+            part = np.concatenate([part, np.full(tile - len(part), part[0],
+                                                 dtype=np.int64)])
+            # clamped gather: min(arange, len-1) replicates the last point
+            ia = start_a[part, None] + np.minimum(
+                np.arange(m_pad)[None, :], (len_a[part] - 1)[:, None])
+            ib = start_b[part, None] + np.minimum(
+                np.arange(n_pad)[None, :], (len_b[part] - 1)[:, None])
+            c = np.asarray(kops.bucketed_min_core(
+                tuple(p[ia] for p in planes), tuple(p[ib] for p in planes),
+                interpret=interpret))
+            np.minimum.at(out, pair_idx[part], c)
+            REFINE.launches += 1
+            REFINE.vertex_pairs += tile * m_pad * n_pad
     return core_to_dist(out, metric)
 
 

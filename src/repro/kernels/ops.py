@@ -425,8 +425,9 @@ def merge_join_ranks(table, probes, backend: str | None = None,
             def accel(backend=backend):
                 # pow2 size classes bound jit recompiles; the int64-max
                 # sentinel compares greater than every probe, so table
-                # padding never changes a rank, and padded probe rows are
-                # sliced off below
+                # padding never changes a rank. Padded probe rows are
+                # sliced off on the host: a device-side slice at the probe
+                # count would be a program of its own per count
                 t_hi, t_lo = split_key_planes(
                     _pad_pow2(table, (1 << 63) - 1))
                 p_hi, p_lo = split_key_planes(_pad_pow2(probes, 0))
@@ -434,7 +435,7 @@ def merge_join_ranks(table, probes, backend: str | None = None,
                 if backend == "cpu":
                     out = _mj.merge_join_ranks_host(*planes, side=side)
                     if side != "both":
-                        return call.down(out[:m]).astype(np.int64)
+                        return call.down(out)[:m].astype(np.int64)
                     lo, hi = out
                 elif backend == "kernel" and not _on_tpu():
                     lo, hi = _ranks_ref_jit(*planes)
@@ -442,8 +443,8 @@ def merge_join_ranks(table, probes, backend: str | None = None,
                     lo, hi = _mj.merge_join_ranks(
                         *planes,
                         interpret=backend == "interpret" and not _on_tpu())
-                lo = call.down(lo[:m]).astype(np.int64)
-                hi = call.down(hi[:m]).astype(np.int64)
+                lo = call.down(lo)[:m].astype(np.int64)
+                hi = call.down(hi)[:m].astype(np.int64)
                 return ((lo, hi) if side == "both"
                         else (lo if side == "left" else hi))
 
@@ -545,7 +546,8 @@ def tree_descend(node_keys, cs_path, box_keys, backend: str = "kernel",
                 ("oracle", oracle),
             ]
         out = _fault.run_op("tree_descend", attempts, validate=_v_mask01)
-        return call.down(out[:b]) != 0
+        # sliced on the host, not per block count on the device
+        return call.down(out)[:b] != 0
 
 
 def tree_descend_sharded(node_keys, cs_path, box_keys,
@@ -642,9 +644,21 @@ def _descend_ref_jit(n_hi, n_lo, cs, b_hi, b_lo):
 
 
 def bloom_probe(bits, keys, k: int = 3, interpret: bool | None = None):
-    """bits (B, W) uint32 pre-gathered filter rows; keys (B,) int64."""
+    """bits (B, W) uint32 pre-gathered filter rows; keys (B,) int64.
+
+    B pads to a power of two (at least 8) with all-zero rows, whose
+    verdicts are sliced off on the host, so the probe programs form one
+    class per power of two and not one per probe count."""
+    bits = np.asarray(bits)
+    keys = np.asarray(keys, dtype=np.int64)
+    b = len(keys)
+    bp = 1 << max(int(b - 1).bit_length(), 3)
+    if bp != b:
+        bits = np.concatenate(
+            [bits, np.zeros((bp - b,) + bits.shape[1:], bits.dtype)])
+        keys = np.concatenate([keys, np.zeros(bp - b, np.int64)])
     with _Dispatch("bloom_probe") as call:
-        keys = np.asarray(keys, dtype=np.int64).view(np.uint64)
+        keys = keys.view(np.uint64)
         lo = call.up((keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
                      .view(np.int32))
         hi = call.up((keys >> np.uint64(32)).astype(np.uint32)
@@ -668,7 +682,7 @@ def bloom_probe(bits, keys, k: int = 3, interpret: bool | None = None):
         else:
             attempts = [("jit", oracle), ("oracle", oracle)]
         out = _fault.run_op("bloom_probe", attempts, validate=_v_mask01)
-        return call.down(out) == 1
+        return call.down(out)[:b] == 1
 
 
 def block_scan(scores, theta: float, interpret: bool | None = None):

@@ -121,6 +121,11 @@ class SpatialServeEngine:
         # (deduped in step()). Serial per-query execution recomputes all
         # of it per tenant.
         self.engine.share_cache = ShareCache(share_cache_max)
+        from ..kernels import ops
+        if ops._on_tpu():
+            # the refinement programs the store's geometries can need are
+            # made here, in set-up, and not while queries are served
+            spatial_join.compile_refine(store.geom_pool)
         self.max_slots = max_slots
         self.max_retries = max_retries
         self.slots: list[tuple[SpatialRequest, QueryCursor] | None] = \
@@ -250,15 +255,22 @@ class SpatialServeEngine:
 
     def counters(self) -> dict:
         """Share-cache lookups and hits (every kind together), the bytes
-        the kernel dispatches uploaded and fetched, and the Phase-3
-        block-product pairs tested and tested on the device, so far."""
+        the kernel dispatches uploaded and fetched, the Phase-3
+        block-product pairs tested and tested on the device, and the exact
+        refinement's geometry pairs, fragment pairs, vertex pairs computed
+        and kernel launches (`spatial_join.REFINE`), so far."""
         sc, fs = self.engine.share_cache, fault.STATE.stats
+        rf = spatial_join.REFINE
         return {"share_lookups": sum(sc.lookups.values()),
                 "share_hits": sum(sc.hits.values()),
                 "h2d_bytes": sum(fs.h2d_bytes.values()),
                 "d2h_bytes": sum(fs.d2h_bytes.values()),
                 "mbr_pairs": spatial_join.PAIRS.tested,
-                "mbr_device_pairs": spatial_join.PAIRS.on_device}
+                "mbr_device_pairs": spatial_join.PAIRS.on_device,
+                "refine_pairs": rf.pairs,
+                "refine_fragment_pairs": rf.fragment_pairs,
+                "refine_vertex_pairs": rf.vertex_pairs,
+                "refine_launches": rf.launches}
 
     def _step(self) -> int:
         self._tick += 1

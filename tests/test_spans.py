@@ -84,7 +84,9 @@ def traced(lgd, tenants, tmp_path_factory):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     # process-wide, unlike the share cache's
-    moved = ("h2d_bytes", "d2h_bytes", "mbr_pairs", "mbr_device_pairs")
+    moved = ("h2d_bytes", "d2h_bytes", "mbr_pairs", "mbr_device_pairs",
+             "refine_pairs", "refine_fragment_pairs", "refine_vertex_pairs",
+             "refine_launches")
     before = warm.counters()
     jax.profiler.start_trace(str(out), profiler_options=opts)
     try:
@@ -205,8 +207,9 @@ def test_tree_descend_counts_its_bytes():
         # hi and lo int32 planes of nodes and boxes, the int32 cs mask
         assert st.h2d_bytes["tree_descend"] == (2 * 4 * n * 4 + n * 4
                                                 + 2 * bp * mp * 4 * 4)
-        # the (b, n) int32 masks of the real blocks come back
-        assert st.d2h_bytes["tree_descend"] == b * n * 4
+        # the padded (bp, n) int32 masks come back; the host slices the
+        # real blocks off, so no program is made per block count
+        assert st.d2h_bytes["tree_descend"] == bp * n * 4
         assert out.shape == (b, n)
     finally:
         fault.STATE.reset()

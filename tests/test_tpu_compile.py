@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.core import spatial_join
 from repro.kernels import (bloom_probe, distance_join, fused_topk_join,
                            geom_refine, mbr_candidates, merge_join, ops,
                            tree_descend)
@@ -99,6 +100,17 @@ def test_geom_refine_compiles(shape, m_pad, n_pad, dims):
     a = tuple(shape((1024, m_pad), F32) for _ in range(dims))
     b = tuple(shape((1024, n_pad), F32) for _ in range(dims))
     _assert_kernel(geom_refine.bucketed_min_core.lower(a, b))
+
+
+@pytest.mark.parametrize("m_pad", [1, 2, 4, 8, 16, 32, 64, 128])
+def test_refine_family_compiles(shape, m_pad):
+    # the refinement's whole program family: every (m_pad, n_pad) size
+    # class at its fixed tile of pairs (core/spatial_join.py::refine_tile)
+    for n_pad in (1, 2, 4, 8, 16, 32, 64, 128):
+        t = spatial_join.refine_tile(m_pad, n_pad)
+        a = tuple(shape((t, m_pad), F32) for _ in range(2))
+        b = tuple(shape((t, n_pad), F32) for _ in range(2))
+        _assert_kernel(geom_refine.bucketed_min_core.lower(a, b))
 
 
 def test_merge_join_ranks_compiles(shape):
